@@ -11,8 +11,10 @@ the harness CSV):
      streaming reducer's largest block is [R, chunk, B] / the [R, L, K]
      carry);
   3. the same engine round on 1 vs 4 fake host devices on a
-     ("data", "rep") mesh (subprocesses, since the device count is fixed at
-     jax init) — scaling sanity on CPU, the real win is on a TPU slice.
+     ("data", "rep") mesh, each in a child process pinned to the CPU (the
+     device count is fixed when jax starts, and a CPU child never competes
+     with this process for a chip) — scaling sanity on CPU, the real win is
+     on a TPU slice. A child that fails fails the run.
 
     PYTHONPATH=src python -m benchmarks.bench_fit [--toy]
 """
@@ -21,7 +23,6 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 import time
 
 import jax
@@ -38,7 +39,8 @@ from repro.optim.optimizers import make_optimizer
 
 from repro.analysis.jaxpr import peak_intermediate_bytes
 
-ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ART = os.path.join(ROOT, "artifacts")
 
 
 # ------------------------------------------------------ host-loop baseline --
@@ -85,30 +87,46 @@ def _time_rounds(fn, n_rounds):
     return (time.time() - t0) / n_rounds
 
 
-_DEVICE_SCRIPT = textwrap.dedent("""
-    import os, sys, time, json
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-    import jax
-    from repro.core.index import IRLIConfig, IRLIIndex
-    from repro.data.synthetic import clustered_ann
+def _device_round(ndev: int, n: int, rounds: int) -> dict:
+    """The same fit on ``ndev`` devices of this process (one device: no
+    mesh; more: a ("data", "rep") mesh)."""
+    from repro.core.index import IRLIIndex
     from repro.launch.mesh import make_fit_mesh
-
-    n, rounds = %d, %d
+    if len(jax.devices()) < ndev:
+        raise RuntimeError(f"{ndev} devices requested, "
+                           f"{len(jax.devices())} visible")
     data = clustered_ann(n_base=n, n_queries=16, d=16, n_clusters=n // 20,
                          k_gt=10, k_train=20, seed=0)
     cfg = IRLIConfig(d=16, n_labels=n, n_buckets=64, n_reps=4, d_hidden=64,
                      K=8, rounds=rounds, epochs_per_round=2, batch_size=512,
                      lr=2e-3, affinity_chunk=512, seed=1)
-    mesh = make_fit_mesh(rep_axis=2) if len(jax.devices()) > 1 else None
+    mesh = make_fit_mesh(ndev, rep_axis=2) if ndev > 1 else None
     idx = IRLIIndex(cfg)
     t0 = time.time()
     stats = idx.fit(data.train_queries, data.train_gt, label_vecs=data.base,
                     mesh=mesh)
-    per_round = (time.time() - t0) / len(stats.round_idx)
-    print(json.dumps({"devices": len(jax.devices()),
-                      "s_per_round": per_round,
-                      "loss": stats.train_loss}))
-""")
+    return {"devices": ndev,
+            "s_per_round": (time.time() - t0) / len(stats.round_idx),
+            "loss": stats.train_loss}
+
+
+def _device_round_child(ndev: int, n: int, rounds: int) -> dict:
+    """:func:`_device_round` in a child process on ``ndev`` fake CPU
+    devices; a non-zero exit raises."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={ndev}").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(ROOT, "src"), ROOT,
+                    env.get("PYTHONPATH")) if p)
+    r = subprocess.run([sys.executable, "-m", "benchmarks.bench_fit",
+                        "--device-round", str(ndev), str(n), str(rounds)],
+                       cwd=ROOT, env=env, capture_output=True, text=True,
+                       timeout=1200)
+    if r.returncode != 0:
+        raise RuntimeError(f"devices={ndev} round failed (exit "
+                           f"{r.returncode}): {r.stderr[-2000:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def run(csv=True, toy=False):
@@ -189,17 +207,10 @@ def run(csv=True, toy=False):
     rec.update(affinity_peak_dense_bytes=dense_b,
                affinity_peak_stream_bytes=stream_b)
 
-    # --- 1 vs 4 fake devices (subprocess; device count fixed at init) ----
+    # --- 1 vs 4 fake CPU devices (a child each) --------------------------
     if not toy:
         for ndev in (1, 4):
-            script = _DEVICE_SCRIPT % (ndev, n, rounds)
-            r = subprocess.run([sys.executable, "-c", script],
-                               capture_output=True, text=True, timeout=1200)
-            if r.returncode != 0:
-                print(f"# devices={ndev} run failed: {r.stderr[-500:]}",
-                      file=sys.stderr)
-                continue
-            out = json.loads(r.stdout.strip().splitlines()[-1])
+            out = _device_round_child(ndev, n, rounds)
             rows.append((f"fit/engine_round_devices={ndev}",
                          out["s_per_round"] * 1e6,
                          f"loss_end={out['loss'][-1]:.3f}"))
@@ -221,5 +232,12 @@ def run(csv=True, toy=False):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--toy", action="store_true",
-                    help="CI smoke: small shapes, no subprocess device runs")
-    run(toy=ap.parse_args().toy)
+                    help="CI smoke: small shapes, no 1-vs-4 device rounds")
+    ap.add_argument("--device-round", nargs=3, type=int,
+                    metavar=("NDEV", "N", "ROUNDS"),
+                    help="run one 1-vs-4 leg here and print it as JSON")
+    args = ap.parse_args()
+    if args.device_round:
+        print(json.dumps(_device_round(*args.device_round)))
+    else:
+        run(toy=args.toy)

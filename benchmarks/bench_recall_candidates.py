@@ -45,7 +45,7 @@ def run(csv=True):
     # with the dense rows above whenever topC covers the survivors
     for m in (1, 2, 4):
         pipe = SearchParams(mode="compact", m=m, tau=1, k=10,
-                            topC=1024).pipeline()
+                            topC=1024).resolve(len(data.base)).pipeline()
         t0 = time.time()
         cands = pipe.candidates(idx.params, idx.index.members,
                                 jnp.asarray(data.queries))

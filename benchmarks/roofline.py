@@ -23,9 +23,6 @@ import os
 PEAK_FLOPS = 197e12      # bf16 / chip
 HBM_BW = 819e9           # bytes/s
 ICI_BW = 50e9            # bytes/s/link
-VMEM_BYTES = 16 << 20    # on-chip vector memory / core (TPU v4/v5e class);
-#                          the megakernel's tile budget derives from here
-#                          (repro.kernels.mega_query.ops), never hardcoded
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
 

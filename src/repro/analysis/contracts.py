@@ -17,7 +17,7 @@ A :class:`Contract` bundles:
                                         dims (non-vacuity sighting)
       ``max_intermediate_bytes(2**20)`` peak single traced intermediate
       ``max_dispatches(1)``             at most N top-level dispatch eqns
-                                        (pjit/pallas_call) in the trace —
+                                        (jit/pallas_call) in the trace —
                                         the fused-megakernel proof
       ``require_dtype_free(np.float32, "L", "D")``
                                         no intermediate of that dtype
@@ -159,7 +159,7 @@ def max_intermediate_bytes(limit: int):
 
 
 def max_dispatches(limit: int):
-    """At most ``limit`` top-level dispatch eqns (pjit / pallas_call) in
+    """At most ``limit`` top-level dispatch eqns (jit / pallas_call) in
     the fixture's trace — the single-launch proof of a fused pipeline.
     Negative: the control (the per-stage split of the same computation)
     must exceed the limit, or the counter went blind."""

@@ -284,7 +284,7 @@ def sharded_fit_round() -> Fixture:
     round_fn = eng._sharded_round(mesh, data, state)
 
     def fn(s, i, ww):
-        ns, m = round_fn(s, i, ww)
+        ns, m = round_fn(s, i, ww, data)
         return jnp.sum(ns.assign), m["loss"]
     S = idx.shape[0]
     return Fixture(fn=fn, args=(state, idx, w),

@@ -4,9 +4,9 @@ fit [R, L, B]) and the per-contract peak-intermediate-bytes report.
 
 Promoted from ``benchmarks/jaxpr_walk.py`` (which remains as a deprecated
 re-exporting shim): one copy, so a JAX representation change (the
-pjit/scan sub-jaxpr layout, a new control-flow primitive) gets fixed here,
+jit/scan sub-jaxpr layout, a new control-flow primitive) gets fixed here,
 not in drifting clones. The walk recurses EXPLICITLY into the sub-jaxpr
-params of ``pjit``/``scan``/``cond``/``while`` (ClosedJaxpr), ``shard_map``
+params of ``jit``/``scan``/``cond``/``while`` (ClosedJaxpr), ``shard_map``
 and ``pallas_call`` (raw Jaxpr) and lists/tuples of either — the shapes it
 yields inside ``shard_map`` are the PER-SHARD block shapes, which is
 exactly what a per-device memory contract wants to see.
@@ -37,7 +37,7 @@ def _sub_jaxprs(p):
 
 
 def iter_eqns(jaxpr):
-    """Every equation in a jaxpr, recursing into sub-jaxprs (pjit/scan/
+    """Every equation in a jaxpr, recursing into sub-jaxprs (jit/scan/
     cond/while bodies, shard_map and pallas_call kernels)."""
     for eqn in jaxpr.eqns:
         yield eqn
@@ -84,11 +84,11 @@ def materializes_dims(fn, args, *dims, dtype=None):
 
 #: top-level primitives that launch device work as a separate dispatch —
 #: a jitted call and a bare pallas_call each cost one kernel round-trip
-_DISPATCH_PRIMITIVES = ("pjit", "pallas_call")
+_DISPATCH_PRIMITIVES = ("jit", "pallas_call")
 
 
 def count_dispatches(fn, args) -> int:
-    """Number of TOP-LEVEL dispatch sites (pjit / pallas_call eqns) in the
+    """Number of TOP-LEVEL dispatch sites (jit / pallas_call eqns) in the
     trace of ``fn(*args)``. Deliberately NOT recursive — a jit that nests
     further jits/pallas_calls still launches as one fused executable, while
     N sibling eqns at the top level are N separate dispatches with an HBM

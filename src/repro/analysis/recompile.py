@@ -31,16 +31,9 @@ import dataclasses
 
 
 def jit_cache_size(jitted) -> int:
-    """Number of retained traces of a ``jax.jit`` callable (0 before the
-    first call). Works on both pinned jax 0.4.37 and latest."""
-    try:
-        return int(jitted._cache_size())
-    except AttributeError:
-        pass
-    try:    # newer spelling, kept for the latest-jax CI leg
-        return int(jitted._cached_fun_cache_size())
-    except AttributeError:
-        return 0
+    """Number of retained traces of a ``jax.jit`` callable, or of the one
+    a ``functools.partial`` binds (0 before the first call)."""
+    return int(getattr(jitted, "func", jitted)._cache_size())
 
 
 class TraceCounter:

@@ -42,6 +42,10 @@ STORE_DTYPE = "int8"
 STORE_BLOCK = 32
 N_SCALE_BLOCKS = D // STORE_BLOCK
 
+# serve knobs of the serve_query cell: top-m probe width, frequency
+# threshold tau, final top-k
+SERVE_M, SERVE_TAU, SERVE_K = 5, 2, 10
+
 SCORER_CFG = ScorerConfig(d_in=D, d_hidden=HIDDEN, n_buckets=B_BUCKETS,
                           n_reps=R, loss="softmax_bce")
 
@@ -174,7 +178,8 @@ def _serve_cell() -> CellDef:
         in_specs=lambda mesh: {"queries": P()},
         params=params_for, param_specs=param_specs,
         step=lambda mesh: S.build_irli_serve(
-            mesh, m=5, tau=2, k=10, store_dtype=STORE_DTYPE,
+            mesh, m=SERVE_M, tau=SERVE_TAU, k=SERVE_K,
+            store_dtype=STORE_DTYPE,
             store_block=STORE_BLOCK),
         step_with_mesh=True,
         note="every chip = one paper node; sorted-frequency candidate path; "

@@ -38,31 +38,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import search_api as SA
 from repro.core.search_api import SearchParams, SearchResult
 
-# jax.shard_map landed as a top-level API after 0.4.x; fall back to the
-# experimental module (same semantics, `check_rep` instead of `check_vma`)
-if hasattr(jax, "shard_map"):
-    _shard_map, _SM_KW = jax.shard_map, {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
-
-# public re-export: every other mesh program in the repo (fit engine, launch
-# cells) routes through the same version-compat shim instead of redoing the
-# 0.4.x/experimental probe
-shard_map_compat, SHARD_MAP_COMPAT_KW = _shard_map, _SM_KW
-
-
-def _resolve(params: SearchParams, L_loc: int, q_batch: int,
-             *, force_compact: bool = False) -> SearchParams:
-    """Resolve mode against the PER-SHARD corpus size. The production path
-    (shard_search_local / make_production_search) exists for corpora where
-    dense would OOM, so it pins compact regardless of auto-resolution."""
+def _resolve(params: SearchParams, L_loc: int, q_batch: int, members,
+             delta_members=None, *, force_compact: bool = False
+             ) -> SearchParams:
+    """Resolve mode and kernels against the PER-SHARD corpus size and member
+    geometry. The production path (shard_search_local /
+    make_production_search) exists for corpora where dense would OOM, so it
+    pins compact regardless of auto-resolution."""
     if force_compact:
         if params.mode == "dense":
             raise ValueError("the production sharded path is compact-only "
                              "(dense would materialize [Q, L_loc] per shard)")
-        return params.replace(mode="compact")
-    return params.resolve(L_loc, q_batch)
+        params = params.replace(mode="compact")
+    return params.resolve(
+        L_loc, q_batch,
+        width=SA.candidate_width(params.m, members, delta_members))
 
 
 def _local_arrays(scorer_params, members, base_shard, queries,
@@ -126,7 +116,8 @@ def local_search(scorer_params, members, base_shard, queries,
         params = SA.params_from_legacy_kwargs(
             "distributed.local_search", m=m, tau=tau, k=k, metric=metric,
             mode=mode, topC=topC)
-        r = _resolve(params, base_shard.shape[0], queries.shape[0])
+        r = _resolve(params, base_shard.shape[0], queries.shape[0], members,
+                     delta_members)
         ids, scores, _ = _local_arrays(scorer_params, members, base_shard,
                                        queries, r, delta_members, tombstone,
                                        cache)
@@ -134,12 +125,13 @@ def local_search(scorer_params, members, base_shard, queries,
     SA.check_params("distributed.local_search", params)
     if any(v is not None for v in (m, tau, k, loss_kind, metric, mode, topC)):
         raise TypeError("pass either SearchParams or legacy kwargs, not both")
-    r = _resolve(params, base_shard.shape[0], queries.shape[0])
+    r = _resolve(params, base_shard.shape[0], queries.shape[0], members,
+                 delta_members)
     ids, scores, n_cand = _local_arrays(scorer_params, members, base_shard,
                                         queries, r, delta_members, tombstone,
                                         cache)
     return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                        epoch=epoch, mode=r.mode)
+                        epoch=epoch, mode=r.mode, kernels=r.kernels)
 
 
 def _merge_across_shards(ids, scores, n_cand, k: int, axes):
@@ -189,7 +181,7 @@ def make_distributed_search(mesh: Mesh, params: SearchParams | None = None, *,
         base = _strip_block(base)
         # shard-local search (compact mode keeps the per-shard work O(topC)
         # per query ahead of the tiny all_gather merge)
-        r = _resolve(sp, base.shape[0], queries.shape[0])
+        r = _resolve(sp, base.shape[0], queries.shape[0], members)
         ids, scores, n_cand = _local_arrays(scorer_params, members, base,
                                             queries, r, None, None, cache)
         # globalize ids: offset by shard start (-1 "no candidate" stays -1)
@@ -201,21 +193,22 @@ def make_distributed_search(mesh: Mesh, params: SearchParams | None = None, *,
         # in_specs depend on the base payload's pytree structure (raw array
         # vs QuantizedStore leaves), so the shard_map is built per call —
         # the jit cache downstream still keys on structure, not identity
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             sharded, mesh=mesh,
             in_specs=(P(*(corpus_axes + (None,))),  # params leading shard axis
                       P(*(corpus_axes + (None, None, None))),  # members
                       _base_specs(base, corpus_axes),
                       P()),                                # queries replicated
             out_specs=(P(), P(), P()),
-            **_SM_KW)
+            check_vma=False)
         ids, scores, n_cand = mapped(scorer_params, members, base, queries)
         if legacy:
             return ids, scores
         L_loc = base.shape[1]
-        resolved = _resolve(sp, L_loc, queries.shape[0])
+        resolved = _resolve(sp, L_loc, queries.shape[0], members[0])
         return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                            epoch=epoch, mode=resolved.mode)
+                            epoch=epoch, mode=resolved.mode,
+                            kernels=resolved.kernels)
 
     return search
 
@@ -264,7 +257,7 @@ def shard_search_local(scorer_params, members, base_shard, queries,
     Qn = queries.shape[0]
     chunked = not (Qn <= q_chunk or Qn % q_chunk != 0)
     r = _resolve(params, base_shard.shape[0], q_chunk if chunked else Qn,
-                 force_compact=True)
+                 members, delta_members, force_compact=True)
 
     def chunk(qs):
         return _local_arrays(scorer_params, members, base_shard, qs,
@@ -281,7 +274,7 @@ def shard_search_local(scorer_params, members, base_shard, queries,
     if legacy:
         return ids, scores
     return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                        epoch=epoch, mode="compact")
+                        epoch=epoch, mode="compact", kernels=r.kernels)
 
 
 def make_production_search(mesh: Mesh, params: SearchParams | None = None, *,
@@ -318,7 +311,8 @@ def make_production_search(mesh: Mesh, params: SearchParams | None = None, *,
     def local(scorer_params, members, base, queries):
         members = members[0]          # strip the shard-leading dim
         base = _strip_block(base)     # raw array or QuantizedStore leaves
-        r = _resolve(sp, base.shape[0], queries.shape[0], force_compact=True)
+        r = _resolve(sp, base.shape[0], queries.shape[0], members,
+                     force_compact=True)
         ids, scores, n_cand = _local_arrays(scorer_params, members, base,
                                             queries, r, None, None, cache)
         # globalize ids and merge
@@ -327,17 +321,19 @@ def make_production_search(mesh: Mesh, params: SearchParams | None = None, *,
         return _merge_across_shards(gids, scores, n_cand, sp.k, axes)
 
     def search(scorer_params, members, base, queries):
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(axes, None, None, None),
                       _base_specs(base, axes), P()),
             out_specs=(P(), P(), P()),
-            **_SM_KW)
+            check_vma=False)
         ids, scores, n_cand = mapped(scorer_params, members, base, queries)
         if legacy:
             return ids, scores
+        r = _resolve(sp, base.shape[1], queries.shape[0], members[0],
+                     force_compact=True)
         return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                            epoch=epoch, mode="compact")
+                            epoch=epoch, mode="compact", kernels=r.kernels)
 
     return search
 

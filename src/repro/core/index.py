@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +59,8 @@ class FitStats:
     train_loss: list      # per round: mean of that round's per-epoch means
     epoch_loss: list = dataclasses.field(default_factory=list)  # per round:
     #                     the [epochs_per_round] per-epoch mean losses
+    dropped_slots: int = 0  # (rep, label) slots the fitted index's member
+    #                     matrix could not hold (InvertedIndex.dropped)
 
 
 class IRLIIndex:
@@ -144,6 +147,17 @@ class IRLIIndex:
         self.assign = state.assign
         self.key = state.rng
         self.build_index()
+        # a bucket loaded past the member width loses the overflow from that
+        # rep: counted, reported and warned about, never silent
+        stats.dropped_slots = self.index.dropped
+        if registry is not None:
+            registry.gauge("fit_index_dropped_slots").set(stats.dropped_slots)
+        if stats.dropped_slots:
+            warnings.warn(
+                f"fit: {stats.dropped_slots} of {cfg.n_reps * cfg.n_labels} "
+                f"(rep, label) slots overflow the member width "
+                f"{self.index.max_load} and are left out of the index",
+                RuntimeWarning, stacklevel=2)
         return stats
 
     def _record_fit_round(self, rnd: int, met: dict, seconds: float,

@@ -12,6 +12,7 @@ of our TPU-native redesign.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,12 @@ class InvertedIndex:
     load: jnp.ndarray      # [R, B] int32
     max_load: int
 
+    @property
+    def dropped(self) -> int:
+        """(rep, label) slots past ``max_load`` that the member matrix does
+        not hold: those labels cannot be reached through that rep."""
+        return int(jnp.sum(jnp.maximum(self.load - self.max_load, 0)))
+
 
 def build_inverted_index(assign: jnp.ndarray, B: int,
                          max_load: int | None = None) -> InvertedIndex:
@@ -59,30 +66,37 @@ def build_inverted_index(assign: jnp.ndarray, B: int,
     Sort labels by bucket id; rank-within-bucket via stable cumcount; scatter
     into [B, max_load]. max_load defaults to the observed max (static at
     trace time when assign is concrete; callers pass an explicit bound inside
-    jit).
+    jit). Labels past ``max_load`` in a bucket are left out and counted by
+    ``InvertedIndex.dropped``. ``assign`` may be sharded (e.g. over the fit
+    mesh's "rep" axis): the rebuild is one jitted program, so its ops follow
+    the input sharding.
     """
-    R, L = assign.shape
     ld = loads(assign, B)
     if max_load is None:
         max_load = int(jnp.max(ld))
+    members = _member_matrix(assign, B, max_load)
+    return InvertedIndex(members=members, load=ld, max_load=max_load)
+
+
+@partial(jax.jit, static_argnames=("B", "max_load"))
+def _member_matrix(assign, B: int, max_load: int):
+    L = assign.shape[1]
 
     def one_rep(a):
         order = jnp.argsort(a, stable=True)            # labels grouped by bucket
         sorted_b = a[order]
-        # rank of each label within its bucket
-        idx = jnp.arange(L)
-        start_of_bucket = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32),
-             jnp.cumsum(jnp.bincount(sorted_b, length=B)).astype(jnp.int32)[:-1]])
-        rank = idx - start_of_bucket[sorted_b]
+        # rank of each label within its bucket: position minus the bucket's
+        # start (an exclusive cumsum of the bucket sizes)
+        counts = jnp.bincount(sorted_b, length=B).astype(jnp.int32)
+        start_of_bucket = jnp.cumsum(counts) - counts
+        rank = jnp.arange(L) - start_of_bucket[sorted_b]
         mem = jnp.full((B, max_load), -1, jnp.int32)
-        ok = rank < max_load
-        mem = mem.at[sorted_b, jnp.clip(rank, 0, max_load - 1)].set(
-            jnp.where(ok, order.astype(jnp.int32), -1))
-        return mem
+        # ranks past max_load fall outside the matrix and are dropped (a
+        # clipped index would overwrite the bucket's last kept member)
+        return mem.at[sorted_b, rank].set(order.astype(jnp.int32),
+                                          mode="drop")
 
-    members = jax.vmap(one_rep)(assign)
-    return InvertedIndex(members=members, load=ld, max_load=max_load)
+    return jax.vmap(one_rep)(assign)
 
 
 def bucket_targets(assign: jnp.ndarray, label_ids: jnp.ndarray,

@@ -170,16 +170,17 @@ def sorted_frequency_topC(cands: jnp.ndarray, C: int):
     return jax.vmap(one)(cands)
 
 
-def frequency_topC(cands: jnp.ndarray, C: int):
+def frequency_topC(cands: jnp.ndarray, C: int, kernel: bool = False):
     """FrequentOnes over gathered candidates -> compact (ids, counts) [Q, C].
 
     Dispatches through kernels/freq_topc/ops (the ONE dispatch site): the
-    fused Pallas kernel on TPU — per-query bitonic sort + run-length count
-    + top-C, VMEM-resident — while the packed sort keys fit int32, the jnp
-    sorted path elsewhere. Both produce identical output (count desc, id
-    asc on ties; -1/0 padding past the distinct-candidate count)."""
+    fused Pallas kernel — per-query bitonic sort + run-length count + top-C,
+    VMEM-resident — when ``kernel`` is set (the resolved choice, see
+    :func:`select_kernels`), the jnp sorted path otherwise. Both produce
+    identical output (count desc, id asc on ties; -1/0 padding past the
+    distinct-candidate count)."""
     from repro.kernels.freq_topc.ops import frequent_topc
-    return frequent_topc(cands, C=C)
+    return frequent_topc(cands, C=C, kernel=kernel)
 
 
 def pairwise_sim(queries, base, metric: str = "angular"):
@@ -189,11 +190,13 @@ def pairwise_sim(queries, base, metric: str = "angular"):
     (dense), gathered (compact, via :func:`gathered_sim`), the store's
     exact refine stage, and the distance_topk kernel oracle — routes here
     so numerics can't diverge."""
+    hi = jax.lax.Precision.HIGHEST     # the chip's default is one bf16 pass
     if metric == "angular":
-        return jnp.einsum("qd,ld->ql", queries, base,
+        return jnp.einsum("qd,ld->ql", queries, base, precision=hi,
                           preferred_element_type=jnp.float32)
     return -(jnp.sum(queries ** 2, 1, keepdims=True)
-             - 2 * queries @ base.T + jnp.sum(base ** 2, 1)[None, :])
+             - 2 * jnp.matmul(queries, base.T, precision=hi)
+             + jnp.sum(base ** 2, 1)[None, :])
 
 
 def gathered_sim(queries, vecs, metric: str = "angular"):
@@ -262,27 +265,68 @@ def exact_topk(queries, base, tombstone, *, k: int, metric: str = "angular"):
     return rerank(queries, base, ~tombstone[None, :], k, metric)
 
 
+def check_kernels(kernels, mode: str) -> None:
+    """Validate a ``kernels`` tuple against the pipeline mode."""
+    if not isinstance(kernels, tuple) or any(k not in KERNELS
+                                             for k in kernels):
+        raise ValueError(f"kernels must be a tuple drawn from {KERNELS}, "
+                         f"got {kernels!r}")
+    if kernels and mode == "dense":
+        raise ValueError("mode='dense' runs no Pallas kernel")
+
+
+@partial(jax.jit, static_argnames=("k", "metric", "group"))
+def _exact_block(queries, base, *, k: int, metric: str, group: int):
+    sim = pairwise_sim(queries, base, metric)                   # [q, L]
+    Qb, L = sim.shape
+    pad = (-L) % group
+    sim = jnp.pad(sim, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    gmax = jnp.max(sim.reshape(Qb, -1, group), axis=-1)         # [q, L/g]
+    _, gid = jax.lax.top_k(gmax, min(k, gmax.shape[1]))         # [q, k]
+    cand = (gid[:, :, None] * group
+            + jnp.arange(group, dtype=gid.dtype)).reshape(Qb, -1)
+    vals, pos = jax.lax.top_k(jnp.take_along_axis(sim, cand, axis=1), k)
+    ids = jnp.take_along_axis(cand, pos, axis=1)
+    return jnp.where(jnp.isfinite(vals), ids, -1)
+
+
+def exact_neighbors(queries, base, k: int, *, metric: str = "angular",
+                    q_block: int = 256, group: int = 128):
+    """Exact top-k ids [Q, k] of every query over the whole corpus — the
+    ground truth for recall and the training labels of an ANN fit, computed
+    on the device in blocks of ``q_block`` queries.
+
+    Each block scores all L rows (:func:`pairwise_sim`, the metric every
+    rerank shares), keeps the k groups of ``group`` consecutive rows with
+    the largest maxima, and takes the top-k of those k·group scores. That
+    is exact: each of the k best rows lies in a group whose maximum is at
+    least the k-th best score, and at most k groups have such a maximum. It
+    avoids a top-k over the full row width, which the chip sorts slowly.
+    ``Q`` must be a multiple of ``q_block`` (or smaller than it)."""
+    Q = queries.shape[0]
+    q_block = min(q_block, Q)
+    if Q % q_block:
+        raise ValueError(f"{Q} queries are not a multiple of the "
+                         f"{q_block}-query block")
+    out = [_exact_block(queries[s:s + q_block], base, k=k, metric=metric,
+                        group=group) for s in range(0, Q, q_block)]
+    return jnp.concatenate(out, axis=0)
+
+
 # ------------------------------------------------------------ pipeline ------
 DENSE_TABLE_BUDGET_BYTES = 64 << 20   # default cap on the [Q, L] fp32 tables
 
 
 def select_mode(L: int, q_batch: int = 512,
                 budget_bytes: int = DENSE_TABLE_BUDGET_BYTES,
-                store_dtype: str = "fp32", *, m: int | None = None,
-                topC: int | None = None, refine_k: int | None = None,
-                k: int | None = None) -> str:
+                store_dtype: str = "fp32") -> str:
     """Pick the frequency/rerank backend from the per-shard corpus size.
 
     dense materializes two [q_batch, L] fp32 tables (counts + similarities);
     compact's intermediates are O(q_batch · C0). Returns "dense" while the
-    tables fit the budget, else "compact" — unless the caller passes the
-    probe/rerank knobs (``m``, ``topC``, ``refine_k``, ``k``), in which
-    case the fused megakernel ("mega", kernels/mega_query) is preferred
-    over compact whenever its VMEM tile footprint fits the roofline budget
-    (``mega_fits``): oversized knob combos — candidate widths past the
-    freq_topc sort bound, or tile sets past the VMEM budget — fall back to
-    compact instead of failing at lowering. Without the knobs the legacy
-    dense/compact rule applies unchanged (``QueryPipeline.make``).
+    tables fit the budget, else "compact". "mega" is never picked: its
+    Pallas kernel has not run on a chip, and its fused-jit form only
+    repackages compact (a request may still name it).
 
     The dense accounting is CODE bytes, not fp32 bytes: a quantized store
     (``store_dtype`` != "fp32") holds int8/bf16 codes, and dense's
@@ -291,13 +335,36 @@ def select_mode(L: int, q_batch: int = 512,
     so auto never resolves dense for quantized stores."""
     dense_fits = (store_dtype == "fp32"
                   and 2 * q_batch * L * 4 <= budget_bytes)
-    if dense_fits:
-        return "dense"
-    if None not in (m, topC, refine_k, k):
-        from repro.kernels.mega_query.ops import mega_fits
-        if mega_fits(m, topC, refine_k, k):
-            return "mega"
-    return "compact"
+    return "dense" if dense_fits else "compact"
+
+
+#: The Pallas kernels a pipeline can route its stages through.
+KERNELS = ("freq_topc", "quant_rerank")
+
+
+def select_kernels(mode: str, store_dtype: str, width: int | None,
+                   backend: str) -> tuple:
+    """The Pallas kernels a resolved pipeline runs — decided ONCE, where the
+    request's params resolve, and never revisited in dispatch.
+
+    Kernels run only on a "tpu" ``backend`` and only in the compact stage
+    sequence (mode "compact", or "mega"'s fused jit of it):
+
+      * "freq_topc" while the padded candidate width (``width`` = R·m·(ML
+        [+ DL]) slots per query) fits the kernel's packed sort keys
+        (``MAX_WIDTH``); an unknown or wider width resolves to the jnp
+        sort, and the resolved ``kernels`` tuple says so;
+      * "quant_rerank" for int8/bf16 stores (the coarse stage).
+    """
+    if backend != "tpu" or mode == "dense":
+        return ()
+    from repro.kernels.freq_topc.freq_topc import MAX_WIDTH, pow2_width
+    out = []
+    if width is not None and pow2_width(width) <= MAX_WIDTH:
+        out.append("freq_topc")
+    if store_dtype != "fp32":
+        out.append("quant_rerank")
+    return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,12 +383,15 @@ class QueryPipeline:
     while dense counts every survivor.
 
     mode="mega" is the compact path as ONE fused dispatch
-    (kernels/mega_query, docs/query_paths.md): the Pallas megakernel on
-    TPU when the shapes fit its VMEM budget, a single jit of the verbatim
-    compact op sequence everywhere else — so results are bit-identical to
-    mode="compact" on every surface, including streaming delta/tombstone
-    state and ``adaptive_m`` (contract ``query.mega_single_dispatch``;
-    parity pinned by tests/test_mega_query.py).
+    (kernels/mega_query, docs/query_paths.md): a single jit of the verbatim
+    compact op sequence — so results are bit-identical to mode="compact" on
+    every surface, including streaming delta/tombstone state and
+    ``adaptive_m`` (contract ``query.mega_single_dispatch``; parity pinned
+    by tests/test_mega_query.py).
+
+    ``kernels`` lists the Pallas kernels the stages run (see
+    :func:`select_kernels`, which fills it when params resolve); the
+    default () runs every stage as jnp.
 
     ``store_dtype`` selects the vector-payload tier (docs/store.md): "fp32"
     reranks gathered raw rows (bit-identical whether ``base`` is an array
@@ -344,6 +414,7 @@ class QueryPipeline:
     #                                (probe_mass=1.0 takes the EXACT
     #                                non-adaptive trace, so toggling
     #                                adaptive_m alone changes nothing)
+    kernels: tuple = ()            # Pallas kernels the stages run (KERNELS)
     # no loss_kind: bucket selection works on raw logits, which give the
     # same top-m as softmax OR sigmoid probabilities (both monotone) — the
     # training loss is irrelevant at serve time
@@ -363,6 +434,7 @@ class QueryPipeline:
             raise ValueError(
                 "mode='dense' requires store_dtype='fp32' — the dense "
                 "rerank would decode the whole [L, D] store back to fp32")
+        check_kernels(self.kernels, self.mode)
 
     @classmethod
     def make(cls, L: int, *, mode: str = "auto", q_batch: int = 512,
@@ -417,20 +489,22 @@ class QueryPipeline:
         """
         store = self.resolve_store(base)
         if self.mode == "mega":
-            # the ONE fused dispatch (kernels/mega_query): Pallas kernel
-            # when eligible, a single jit of the compact sequence otherwise
+            # the ONE fused dispatch (kernels/mega_query): a single jit of
+            # the compact sequence
             from repro.kernels.mega_query.ops import mega_search
             return mega_search(self, params, members, base, queries,
                                delta_members, tombstone)
         cands = self.candidates(params, members, queries, delta_members,
                                 tombstone)
         if self.mode == "compact":
-            cid, cnt = frequency_topC(cands, self.topC)
+            cid, cnt = frequency_topC(cands, self.topC,
+                                      kernel="freq_topc" in self.kernels)
             if store is not None and store.dtype != "fp32":
                 from repro.store.rerank import rerank_two_stage
                 ids, scores = rerank_two_stage(
                     queries, store, cid, cnt, tau=self.tau, k=self.k,
-                    refine_k=self.refine_k, metric=self.metric)
+                    refine_k=self.refine_k, metric=self.metric,
+                    kernel="quant_rerank" in self.kernels)
             else:
                 rows = store.codes if store is not None else base
                 ids, scores = rerank_gathered(queries, rows, cid, cnt,
@@ -511,12 +585,12 @@ class QueryPipeline:
 @partial(jax.jit, static_argnames=("pipe",))
 def _stage_mega(pipe: QueryPipeline, params, members, base, queries,
                 delta_members, tombstone):
-    """mode="mega" as one staged unit: jitting the compact twin's search
-    here reproduces ops._fused's trace exactly, so the staged path stays
-    bit-identical to the fused one (the test_obs_integration pin)."""
-    compact = dataclasses.replace(pipe, mode="compact")
-    return compact.search(params, members, base, queries, delta_members,
-                          tombstone)
+    """mode="mega" as one staged unit: the same ops.mega_search dispatch
+    the fused path makes, so the staged path stays bit-identical to it
+    (the test_obs_integration pin)."""
+    from repro.kernels.mega_query.ops import mega_search
+    return mega_search(pipe, params, members, base, queries, delta_members,
+                       tombstone)
 
 
 @partial(jax.jit, static_argnames=("pipe",))
@@ -546,7 +620,8 @@ def _stage_gather(pipe: QueryPipeline, members, bidx, probe_keep,
 
 @partial(jax.jit, static_argnames=("pipe",))
 def _stage_freq_topc(pipe: QueryPipeline, cands):
-    cid, cnt = frequency_topC(cands, pipe.topC)
+    cid, cnt = frequency_topC(cands, pipe.topC,
+                              kernel="freq_topc" in pipe.kernels)
     n_cand = jnp.sum((cid >= 0) & (cnt >= pipe.tau), axis=1)
     return cid, cnt, n_cand
 
@@ -561,7 +636,8 @@ def _stage_rerank_gathered(pipe: QueryPipeline, queries, rows, cid, cnt):
 def _stage_quant_coarse(pipe: QueryPipeline, queries, store, cid, cnt):
     from repro.store.rerank import coarse_stage
     return coarse_stage(queries, store, cid, cnt, tau=pipe.tau, k=pipe.k,
-                        refine_k=pipe.refine_k, metric=pipe.metric)
+                        refine_k=pipe.refine_k, metric=pipe.metric,
+                        kernel="quant_rerank" in pipe.kernels)
 
 
 @partial(jax.jit, static_argnames=("pipe",))

@@ -65,6 +65,9 @@ class SearchParams:
     hot_replicas: bool = False  # gather hot-bucket replica segments built by
     #                            the online refit loop (no-op when the
     #                            serving snapshot carries none)
+    kernels: tuple | None = None  # Pallas kernels the stages run (a subset
+    #                            of query.KERNELS); None = resolve picks
+    #                            them from the backend and geometry
 
     def __post_init__(self):
         for name in ("m", "tau", "k", "topC"):
@@ -99,29 +102,48 @@ class SearchParams:
                 "mode='dense' cannot serve a quantized store "
                 f"(store_dtype={self.store_dtype!r}): the dense rerank "
                 "would decode the whole [L, D] corpus back to fp32")
+        if self.kernels is not None:
+            Q.check_kernels(self.kernels, self.mode)
 
     def replace(self, **kw) -> "SearchParams":
         return dataclasses.replace(self, **kw)
 
-    def resolve(self, n_labels: int, q_batch: int = 512) -> "SearchParams":
-        """Materialize ``mode="auto"`` against the corpus + batch size (the
-        ``query.select_mode`` rule: dense while the [q_batch, n_labels]
-        tables fit the budget — accounting CODE bytes, so a quantized
-        ``store_dtype`` never resolves dense; otherwise the fused
-        megakernel "mega" when this request's (m, topC, refine_k, k) tile
-        footprint fits the VMEM budget (``mega_fits``), compact as the
-        universal fallback). Resolved params are the cache key."""
-        if self.mode != "auto":
-            return self
-        return self.replace(
-            mode=Q.select_mode(n_labels, q_batch,
-                               store_dtype=self.store_dtype,
-                               m=self.m, topC=self.topC,
-                               refine_k=self.refine_k, k=self.k))
+    def resolve(self, n_labels: int, q_batch: int = 512, *,
+                width: int | None = None) -> "SearchParams":
+        """Materialize ``mode="auto"`` and ``kernels=None`` — the ONE place
+        a request's execution path is chosen; dispatch only reads it back.
+
+        mode follows ``query.select_mode``: dense while the
+        [q_batch, n_labels] tables fit the budget — accounting CODE bytes,
+        so a quantized ``store_dtype`` never resolves dense — otherwise
+        compact. kernels follows ``query.select_kernels`` from the backend
+        and ``width``, the candidate slots per query (see
+        :func:`candidate_width`; None = unknown, which keeps the
+        frequency stage on jnp). Explicit kernels are checked against the
+        same facts and never rewritten. Resolved params are the cache
+        key."""
+        mode = self.mode
+        if mode == "auto":
+            mode = Q.select_mode(n_labels, q_batch,
+                                 store_dtype=self.store_dtype)
+        backend = jax.default_backend()
+        kernels = self.kernels
+        if kernels is None:
+            kernels = Q.select_kernels(mode, self.store_dtype, width,
+                                       backend)
+        elif kernels:
+            if backend != "tpu":
+                raise ValueError(f"Pallas kernels {kernels} need a TPU "
+                                 f"backend, not {backend!r}")
+            if "freq_topc" in kernels and "freq_topc" not in \
+                    Q.select_kernels(mode, self.store_dtype, width, backend):
+                raise ValueError(f"freq_topc cannot serve a candidate "
+                                 f"width of {width} slots per query")
+        return self.replace(mode=mode, kernels=kernels)
 
     def pipeline(self) -> Q.QueryPipeline:
         """The QueryPipeline realizing these params. Resolve first."""
-        if self.mode == "auto":
+        if self.mode == "auto" or self.kernels is None:
             raise ValueError("resolve() SearchParams before building a "
                              "pipeline — mode='auto' is not executable")
         return Q.QueryPipeline(m=self.m, tau=self.tau, k=self.k,
@@ -130,7 +152,8 @@ class SearchParams:
                                store_dtype=self.store_dtype,
                                refine_k=self.refine_k,
                                adaptive_m=self.adaptive_m,
-                               probe_mass=float(self.probe_mass))
+                               probe_mass=float(self.probe_mass),
+                               kernels=self.kernels)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,13 +166,15 @@ class SearchResult:
     compact mode, summed over shards on the distributed surfaces),
     ``epoch`` the snapshot epoch served (0 for frozen indexes), and
     ``mode`` the backend that actually ran ("dense" | "compact" | "mega")
-    after auto-resolution.
+    after auto-resolution, and ``kernels`` the Pallas kernels its stages
+    ran (() = every stage in jnp).
     """
     ids: Any
     scores: Any
     n_candidates: Any
     epoch: int = 0
     mode: str = "compact"
+    kernels: tuple = ()
 
 
 @runtime_checkable
@@ -228,7 +253,7 @@ class PipelineCache:
         """:meth:`get` plus a freshness bit -> (fn, fresh). ``fresh`` means
         the entry was just built, i.e. the fn's first call will trace and
         compile — :meth:`search` uses it to time compile latency."""
-        if params.mode == "auto":
+        if params.mode == "auto" or params.kernels is None:
             raise ValueError("PipelineCache keys need resolved params — "
                              "call params.resolve(n_labels, q_batch) first")
         key = (params, int(n_labels), int(q_bucket))
@@ -273,14 +298,17 @@ class PipelineCache:
         registry under ``serve_stage_seconds{stage=...}``. Results are
         bit-identical to the fused path."""
         check_store("PipelineCache.search", params, base)
-        resolved = params.resolve(int(base.shape[0]), int(queries.shape[0]))
+        resolved = params.resolve(
+            int(base.shape[0]), int(queries.shape[0]),
+            width=candidate_width(params.m, members, delta_members))
         if staged:
             pipe = resolved.pipeline()
             ids, scores, n_cand = pipe.search_staged(
                 scorer_params, members, base, queries, delta_members,
                 tombstone, registry=self.registry)
             return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                                epoch=epoch, mode=resolved.mode)
+                                epoch=epoch, mode=resolved.mode,
+                                kernels=resolved.kernels)
         fn, fresh = self._lookup(resolved, base.shape[0], queries.shape[0])
         if fresh:
             from repro import obs
@@ -292,7 +320,17 @@ class PipelineCache:
             ids, scores, n_cand = fn(scorer_params, members, base, queries,
                                      delta_members, tombstone)
         return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
-                            epoch=epoch, mode=resolved.mode)
+                            epoch=epoch, mode=resolved.mode,
+                            kernels=resolved.kernels)
+
+
+def candidate_width(m: int, members, delta_members=None) -> int:
+    """Candidate slots per query of one search: R·m·(ML [+ DL]) from the
+    member matrix [R, B, ML] and the optional delta segments [R, B, DL] —
+    the width ``SearchParams.resolve`` sizes the frequency stage by."""
+    R, _, ML = members.shape
+    DL = 0 if delta_members is None else delta_members.shape[-1]
+    return int(R * m * (ML + DL))
 
 
 #: Process-wide default cache: surfaces that aren't handed a private cache

@@ -84,6 +84,49 @@ def clustered_ann(n_base: int = 20000, n_queries: int = 500, n_train: int | None
     return ANNData(base, queries, train_queries, gt, train_gt, metric)
 
 
+def clustered_ann_device(key, n_base: int, n_queries: int, n_train: int,
+                         d: int, n_clusters: int = 1024,
+                         metric: str = "angular", zipf: float = 1.3):
+    """The clustered_ann recipe generated ON the device, for corpora whose
+    host-side generation and brute-force labels would dominate a run:
+    power-law cluster sizes (Zipf exponent ``zipf`` over a random cluster
+    order; 0 gives equal expected sizes), centers N(0, 3²), members at
+    N(0, 0.7²) around them, and queries drawn as base rows plus N(0, 0.05²)
+    noise — ``train_queries`` separate from ``queries``. Unit-normalized for
+    "angular".
+
+    Returns jnp (base [n_base, d], queries [n_queries, d],
+    train_queries [n_train, d]) float32; exact neighbours come from
+    ``core.query.exact_neighbors``, also on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    kc, ka, kn, kq, kt, kp = jax.random.split(key, 6)
+    centers = jax.random.normal(kc, (n_clusters, d), jnp.float32) * 3.0
+    rank = jax.random.permutation(kp, n_clusters) + 1
+    # inverse-CDF draw: categorical() would build an [n_base, n_clusters]
+    # Gumbel table (64 GB at 2^20 x 2^14)
+    weights = jax.nn.softmax(-zipf * jnp.log(rank.astype(jnp.float32)))
+    cdf = jnp.cumsum(weights)
+    which = jnp.minimum(jnp.searchsorted(cdf, jax.random.uniform(
+        ka, (n_base,)) * cdf[-1]), n_clusters - 1)
+    base = centers[which] + jax.random.normal(kn, (n_base, d)) * 0.7
+
+    def unit(x):
+        if metric != "angular":
+            return x
+        return x / (jnp.linalg.norm(x, axis=1, keepdims=True) + 1e-9)
+
+    base = unit(base)
+
+    def make_queries(k, n):
+        ki, ke = jax.random.split(k)
+        rows = jax.random.randint(ki, (n,), 0, n_base)
+        return unit(base[rows] + jax.random.normal(ke, (n, d)) * 0.05)
+
+    return base, make_queries(kq, n_queries), make_queries(kt, n_train)
+
+
 # ------------------------------------------------------------------- XML ----
 @dataclasses.dataclass
 class XMLData:

@@ -33,7 +33,7 @@ def _kernel(ids_ref, w_ref, table_ref, out_ref, *, max_per_bag: int):
             rid = ids[i]
             valid = rid >= 0
             safe = jnp.maximum(rid, 0)
-            row = pl.load(table_ref, (pl.dslice(safe, 1), slice(None)))[0]
+            row = table_ref[pl.ds(safe, 1), :][0]
             contrib = jnp.where(valid, w[i], 0.0).astype(jnp.float32) \
                 * row.astype(jnp.float32)
             return acc.at[i].add(contrib)

@@ -8,7 +8,7 @@ sort, a segment_sum, and a top_k through HBM; this kernel keeps one query
 tile VMEM-resident end to end:
 
   1. bitonic sort of the candidate row (pads mapped to INT32_MAX so they
-     sort last) — pure vector min/max + static shifts, no lax.sort needed
+     sort last) — pure vector min/max + lane rotations, no lax.sort needed
   2. run-length count via boundary detection + a log-doubling suffix-min
      (next-boundary position minus own position = run length)
   3. top-C by count via a second bitonic pass over packed
@@ -31,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 _SENT = jnp.iinfo(jnp.int32).max   # pads sort last
@@ -40,48 +41,59 @@ _SENT = jnp.iinfo(jnp.int32).max   # pads sort last
 MAX_WIDTH = 32768
 
 
-def _shift_up(x, j, fill):
-    """y[:, i] = x[:, i+j]; the last j lanes take ``fill``."""
-    return jnp.concatenate(
-        [x[:, j:], jnp.full_like(x[:, :j], fill)], axis=1)
+def pow2_width(W: int) -> int:
+    """The bitonic candidate-axis width for W raw candidate slots: the
+    first power of two >= max(W, 128)."""
+    n = 128
+    while n < W:
+        n *= 2
+    return n
 
 
-def _shift_down(x, j, fill):
-    """y[:, i] = x[:, i-j]; the first j lanes take ``fill``."""
-    return jnp.concatenate(
-        [jnp.full_like(x[:, :j], fill), x[:, :-j]], axis=1)
+def _lanes_from(x, s):
+    """y[:, i] = x[:, (i + s) mod n]: a lane rotation by a static or traced
+    offset ``s`` — one vector op on the chip, no relayout."""
+    n = x.shape[-1]
+    return pltpu.roll(x, (n - s) % n, x.ndim - 1)
 
 
 def _bitonic_sort(key, payload=None):
     """Ascending bitonic sort along the last axis (length power of two),
-    optionally permuting ``payload`` identically. Vector min/max + static
-    shifts only — compare-exchange partners (i ^ j) are fetched with a
-    lane shift, so nothing needs a dynamic gather.
+    optionally permuting ``payload`` identically. Vector min/max + lane
+    rotations only — compare-exchange partners (i ^ j) are fetched with a
+    rotation, so nothing needs a dynamic gather. The log2(n)·(log2(n)+1)/2
+    exchange stages run as two nested loops over traced (k, j), so the
+    kernel's code size does not grow with the width.
 
     With a payload, ties in ``key`` would make the exchange ambiguous —
     callers pass keys made unique by packing the lane index in."""
     n = key.shape[-1]
+    log_n = n.bit_length() - 1
     idx = jax.lax.broadcasted_iota(jnp.int32, key.shape, key.ndim - 1)
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            up = _shift_up(key, j, _SENT)
-            down = _shift_down(key, j, _SENT)
-            is_lower = (idx & j) == 0            # partner is at i + j
-            partner = jnp.where(is_lower, up, down)
-            asc = (idx & k) == 0                 # block sort direction
-            keep_min = asc == is_lower
-            take = jnp.where(keep_min, partner < key, partner > key)
-            if payload is not None:
-                p_up = _shift_up(payload, j, 0)
-                p_down = _shift_down(payload, j, 0)
-                p_partner = jnp.where(is_lower, p_up, p_down)
-                payload = jnp.where(take, p_partner, payload)
-            key = jnp.where(take, partner, key)
-            j //= 2
-        k *= 2
-    return key, payload
+
+    def exchange(lk, lj, key, payload):
+        j = jnp.left_shift(1, lj)
+        is_lower = (idx & j) == 0                        # partner at i + j
+        partner = jnp.where(is_lower, _lanes_from(key, j),
+                            _lanes_from(key, n - j))
+        # keep the min iff the block sorts ascending (bit k clear) exactly
+        # when the partner is above (bit j clear): compared as int bits,
+        # since Mosaic cannot select or compare i1 vectors
+        keep_min = ((idx >> lk) & 1) == ((idx >> lj) & 1)
+        new_key = jnp.where(keep_min, jnp.minimum(key, partner),
+                            jnp.maximum(key, partner))
+        if payload is not None:                          # keys are unique
+            p_partner = jnp.where(is_lower, _lanes_from(payload, j),
+                                  _lanes_from(payload, n - j))
+            payload = jnp.where(new_key != key, p_partner, payload)
+        return new_key, payload
+
+    def merge(lk, carry):                                # k = 2^lk blocks
+        def step(t, carry):
+            return exchange(lk, lk - 1 - t, *carry)
+        return jax.lax.fori_loop(0, lk, step, carry)
+
+    return jax.lax.fori_loop(1, log_n + 1, merge, (key, payload))
 
 
 def freq_topc_tile(x, *, n: int, C: int):
@@ -94,15 +106,15 @@ def freq_topc_tile(x, *, n: int, C: int):
     s, _ = _bitonic_sort(x)                              # ascending, pads last
 
     idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    prev = _shift_down(s, 1, -1)                         # s[i-1]; fill != any id
+    prev = jnp.where(idx == 0, -1, _lanes_from(s, n - 1))    # s[i-1]
     boundary = (idx == 0) | (s != prev)                  # run (or pad-region) start
 
     # next boundary after i via suffix-min doubling; run length = next - own
     b = jnp.where(boundary, idx, n)
-    sm = _shift_up(b, 1, n)
+    sm = jnp.where(idx < n - 1, _lanes_from(b, 1), n)
     d = 1
     while d < n:
-        sm = jnp.minimum(sm, _shift_up(sm, d, n))
+        sm = jnp.minimum(sm, jnp.where(idx < n - d, _lanes_from(sm, d), n))
         d *= 2
     cnt = jnp.where(boundary & (s != _SENT), sm - idx, 0)   # [TQ, n]
 
@@ -128,9 +140,7 @@ def freq_topc(cands, *, C: int, tq: int = 8, interpret: bool = False):
     the C most frequent candidate ids per row, count-descending (ties:
     smaller id first); -1/0 past the distinct-candidate count."""
     Q, C0 = cands.shape
-    n = 128
-    while n < C0:
-        n *= 2
+    n = pow2_width(C0)
     if n > MAX_WIDTH:    # not an assert: -O must not turn this into silent
         raise ValueError(  # int32 key overflow and wrong top-C ids
             f"candidate width {C0} overflows int32 packed keys "

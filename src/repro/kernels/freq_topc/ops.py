@@ -1,15 +1,16 @@
-"""jit'd public wrapper and the ONE dispatch site for FrequentOnes top-C:
-the Pallas kernel on TPU while the packed (count, lane) sort keys fit int32,
-the jnp oracle elsewhere (this container is CPU — interpret mode is used by
-tests only)."""
-import jax
+"""jit'd public wrapper and the ONE dispatch site for FrequentOnes top-C.
 
-from repro.kernels.freq_topc.freq_topc import MAX_WIDTH, freq_topc
+Which implementation runs is not decided here: ``kernel`` carries the
+choice made where the request's params resolved (``SearchParams.resolve``
+puts "freq_topc" in ``SearchParams.kernels`` on a TPU while the padded
+candidate width fits ``MAX_WIDTH``). The Pallas kernel and the jnp oracle
+return identical output."""
+from repro.kernels.freq_topc.freq_topc import freq_topc
 from repro.kernels.freq_topc.ref import freq_topc_ref
 
 
-def frequent_topc(cands, *, C: int, tq: int = 8):
-    if jax.default_backend() == "tpu" and cands.shape[1] <= MAX_WIDTH:
+def frequent_topc(cands, *, C: int, kernel: bool = False, tq: int = 8):
+    if kernel:
         return freq_topc(cands, C=C, tq=tq)
     return freq_topc_ref(cands, C=C)
 

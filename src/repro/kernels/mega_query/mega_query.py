@@ -5,7 +5,7 @@ sequence without ever writing an intermediate to HBM:
 
   1. scorer logits + top-m — per rep, the [tq, H] hidden activations are
      one MXU dot; the [H, B] output weights are streamed through VMEM in
-     ``tb``-wide tiles (pl.load from the compiler-placed table) with a
+     ``tb``-wide tiles (ref slices of the compiler-placed table) with a
      running top-m merge (the irli_topk accumulator), so the [tq, B]
      logits row block never exists at once. The adaptive-m(q) keep mask
      (core/query.probe_keep_mask) is computed from a streaming logsumexp
@@ -31,9 +31,12 @@ compact-mode op sequence) — pinned by tests/test_mega_query.py under
 interpret mode.
 
 Tile geometry is NOT hardcoded: callers derive ``tb`` and check the
-resident footprint via :func:`kernel_vmem_bytes` against the budget from
-``benchmarks.roofline.VMEM_BYTES`` (see ops.mega_fits), and the compiled
-kernel is capped with kernels.vmem_limit_bytes.
+resident footprint via :func:`kernel_vmem_bytes` against the VMEM budget of
+the device kind (``repro.kernels.vmem_bytes``, see ops.mega_fits), and the
+compiled kernel is capped with kernels.vmem_limit_bytes.
+
+The kernel has not been compiled for a TPU yet, and no serving path routes
+to it (ops.mega_search runs the compact stages as one jit instead).
 """
 from __future__ import annotations
 
@@ -45,17 +48,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ANY, vmem_limit_bytes
-from repro.kernels.freq_topc.freq_topc import MAX_WIDTH, freq_topc_tile
+from repro.kernels.freq_topc.freq_topc import (MAX_WIDTH, freq_topc_tile,
+                                              pow2_width)
 from repro.kernels.irli_topk.irli_topk import _topk_merge
-
-
-def pow2_width(W: int) -> int:
-    """The bitonic candidate-axis width for W raw candidate slots: the
-    first power of two >= max(W, 128) (the freq_topc tile contract)."""
-    n = 128
-    while n < W:
-        n *= 2
-    return n
 
 
 def kernel_vmem_bytes(*, tq: int, d: int, H: int, B: int, R: int, ML: int,
@@ -115,8 +110,7 @@ def _dma_gather_rows(tab_ref, flat, cand_ref, col0, buf, sem, *, tq: int,
         row = buf[slot, 0]                               # [ML] i32
         if keep_col is not None:
             row = jnp.where(keep_col[i], row, -1)
-        pl.store(cand_ref, (pl.dslice(i, 1), pl.dslice(col0, ML)),
-                 row[None, :])
+        cand_ref[pl.ds(i, 1), pl.ds(col0, ML)] = row[None, :]
         return c
 
     jax.lax.fori_loop(0, tq, body, 0)
@@ -175,8 +169,7 @@ def _kernel(q_ref, w1_ref, b1_ref, b2_ref, w2_ref, members_ref, rows_ref,
         def tile(bi, carry, h=h, b2r=b2r, r=r):
             vals, idxs, mx, se = carry
             # w2 arrives [B, R*H]; one [tb, H] slab per step
-            w2t = pl.load(w2_ref, (pl.dslice(bi * tb, tb),
-                                   slice(r * H, (r + 1) * H)))
+            w2t = w2_ref[pl.ds(bi * tb, tb), r * H:(r + 1) * H]
             lg = jax.lax.dot_general(h, w2t, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             lg = lg + jax.lax.dynamic_slice(b2r, (bi * tb,), (tb,))[None, :]
@@ -219,9 +212,9 @@ def _kernel(q_ref, w1_ref, b1_ref, b2_ref, w2_ref, members_ref, rows_ref,
 
     # ---- stage 4: coarse rerank on streamed code rows --------------------
     def load_coarse(rid):
-        crow = pl.load(rows_ref, (pl.dslice(rid, 1), slice(None)))[0]
+        crow = rows_ref[pl.ds(rid, 1), :][0]
         if kind == "int8":
-            srow = pl.load(scales_ref, (pl.dslice(rid, 1), slice(None)))[0]
+            srow = scales_ref[pl.ds(rid, 1), :][0]
             return crow.astype(jnp.float32) * jnp.repeat(srow, block, axis=0)
         return crow.astype(jnp.float32)
 
@@ -238,7 +231,7 @@ def _kernel(q_ref, w1_ref, b1_ref, b2_ref, w2_ref, members_ref, rows_ref,
 
     def load_refine(rid):
         if has_exact:
-            return pl.load(exact_ref, (pl.dslice(rid, 1), slice(None)))[0]
+            return exact_ref[pl.ds(rid, 1), :][0]
         return load_coarse(rid)
 
     sc2 = _score_slots(q, cids, cids >= 0, load_refine, metric=metric)
@@ -260,7 +253,7 @@ def mega_query(w1, b1, w2, b2, members, rows, scales, exact, queries, *,
 
     Call through ops.mega_search — eligibility (backend, VMEM fit, no
     delta/tombstone) lives there; this wrapper only pads, launches, and
-    unpads. ``interpret=True`` runs the kernel in Pallas interpret mode
+    unpads. ``interpret`` runs the kernel in Pallas interpret mode
     (the parity-test path on CPU).
     """
     R, d, H = w1.shape

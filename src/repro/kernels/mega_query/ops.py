@@ -1,26 +1,23 @@
 """Dispatch + budgeting for the fused query megakernel (mode="mega").
 
 ``mega_search`` is the ONE dispatch site: QueryPipeline.search routes every
-mode="mega" call here, and whichever branch runs, the traced program is
-EXACTLY ONE top-level dispatch (the ``query.mega_single_dispatch``
-contract, registered below):
+mode="mega" call here, and the traced program is EXACTLY ONE top-level
+dispatch (the ``query.mega_single_dispatch`` contract, registered below):
+the compact-mode pipeline as ONE jitted call (``_fused``). Because it jits
+the verbatim compact op sequence, mode="mega" is bit-identical to
+mode="compact" on every surface — the parity suite
+(tests/test_mega_query.py) pins this across stores, metrics, adaptive_m,
+and mutable delta/tombstone/hot-replica state. The stages inside run the
+Pallas kernels that ``pipe.kernels`` names, as compact's do.
 
-  * Pallas branch — TPU backend, shapes fit the VMEM budget, no streaming
-    delta/tombstone state: one pallas_call inside one jit
-    (``_fused_kernel``), the kernel in mega_query.py.
-  * fused-fallback branch — everything else (CPU/GPU CI legs, streaming
-    state, oversized shapes): the compact-mode pipeline as ONE jitted
-    call (``_fused``). Because it jits the verbatim compact op sequence,
-    mode="mega" is bit-identical to mode="compact" on every surface —
-    the parity suite (tests/test_mega_query.py) pins this across stores,
-    metrics, adaptive_m, and mutable delta/tombstone/hot-replica state.
-
-VMEM budgeting (``mega_fits``) is derived, not hardcoded: the budget is a
-fraction of ``benchmarks.roofline.VMEM_BYTES`` and the footprint comes
-from mega_query.kernel_vmem_bytes over the serving geometry — auto mode
-(core/query.select_mode) calls this BEFORE pipeline construction so
-oversized (m, topC, k') combos resolve to compact instead of failing at
-lowering.
+The Pallas megakernel (mega_query.py) is not served: it has not been
+compiled for a chip, so no request can route to it. It stays, with its
+interpret-mode parity tests and the footprint model below, until a PR
+compiles it for a chip and shows where it pays (ROADMAP §1 item 4).
+``mega_fits`` is that footprint model: the budget is a fraction of the
+device kind's per-core VMEM (``repro.kernels.vmem_bytes``, which raises for
+a kind it does not know) and the footprint comes from
+mega_query.kernel_vmem_bytes over the real serving geometry.
 """
 from __future__ import annotations
 
@@ -29,115 +26,66 @@ from functools import partial
 
 import jax
 
-from repro.kernels.freq_topc.freq_topc import MAX_WIDTH
-from repro.kernels.mega_query.mega_query import (kernel_vmem_bytes,
-                                                 mega_query, pow2_width)
+from repro.kernels import vmem_bytes
+from repro.kernels.freq_topc.freq_topc import MAX_WIDTH, pow2_width
+from repro.kernels.mega_query.mega_query import kernel_vmem_bytes
 
-#: Default serving geometry for SHAPE-FREE eligibility (select_mode runs
-#: before members/params exist): the paper's serve config scale — d=128
-#: query dim, H=1024 hidden, B=4096 buckets, R=2 reps, max_load=64,
-#: D=128 payload dim, int8 block 32, tq=8 query rows, tb=512 w2 slab.
-DEFAULT_GEOM = dict(tq=8, d=128, H=1024, B=4096, R=2, ML=64, D=128,
-                    block=32, tb=512)
+#: Tile constants of the megakernel: tq query rows per program, tb-wide
+#: streamed w2 slabs.
+TQ, TB = 8, 512
 
 #: fraction of per-core VMEM the kernel may claim (the rest is the
 #: compiler's for pipelining slack and output staging)
 VMEM_FRACTION = 0.75
 
 
-def _vmem_budget() -> int:
-    """The kernel's VMEM byte budget, read from benchmarks.roofline (the
-    one place that knows the accelerator) — falls back to the 16 MB/core
-    TPU figure when the benchmarks package is not importable (installed
-    library without the repo checkout)."""
-    try:
-        from benchmarks.roofline import VMEM_BYTES
-    except ImportError:
-        VMEM_BYTES = 16 << 20
-    return int(VMEM_FRACTION * VMEM_BYTES)
+def vmem_budget(device_kind: str) -> int:
+    """The kernel's VMEM byte budget on ``device_kind``."""
+    return int(VMEM_FRACTION * vmem_bytes(device_kind))
 
 
 def mega_vmem_bytes(m: int, topC: int, refine_k: int, k: int, *,
-                    geom: dict | None = None) -> int:
-    """Megakernel VMEM footprint of a (m, topC, k', k) knob combo over
-    ``geom`` (DEFAULT_GEOM when None)."""
+                    geom: dict) -> int:
+    """Megakernel VMEM footprint of a (m, topC, k', k) knob combo over the
+    serving geometry ``geom`` (keys d, H, B, R, ML, D, block)."""
     from repro.store.rerank import resolve_refine_k
-    g = dict(DEFAULT_GEOM, **(geom or {}))
-    W = g["R"] * m * g["ML"]
+    W = geom["R"] * m * geom["ML"]
     n = pow2_width(W)
     C = min(topC, W)
     kp = min(resolve_refine_k(refine_k, k, topC), C)
     return kernel_vmem_bytes(
-        tq=g["tq"], d=g["d"], H=g["H"], B=g["B"], R=g["R"], ML=g["ML"],
-        m=m, n=n, C=C, kp=kp, k=min(k, C), tb=min(g["tb"], g["B"]),
-        D=g["D"], block=g["block"])
+        tq=TQ, d=geom["d"], H=geom["H"], B=geom["B"], R=geom["R"],
+        ML=geom["ML"], m=m, n=n, C=C, kp=kp, k=min(k, C),
+        tb=min(TB, geom["B"]), D=geom["D"], block=geom["block"])
 
 
-def mega_fits(m: int, topC: int, refine_k: int, k: int, *,
-              geom: dict | None = None) -> bool:
-    """True iff the megakernel can lower AND fit for these knobs: the
-    candidate width's packed sort keys stay within int32 (the freq_topc
-    MAX_WIDTH bound) and the resident tile set stays within the roofline
-    VMEM budget. This is the auto-mode gate (core/query.select_mode)."""
-    g = dict(DEFAULT_GEOM, **(geom or {}))
-    if pow2_width(g["R"] * m * g["ML"]) > MAX_WIDTH:
+def mega_fits(m: int, topC: int, refine_k: int, k: int, *, geom: dict,
+              device_kind: str) -> bool:
+    """True iff the megakernel can lower AND fit for these knobs on
+    ``device_kind``: the candidate width's packed sort keys stay within
+    int32 (the freq_topc MAX_WIDTH bound) and the resident tile set stays
+    within the VMEM budget."""
+    if pow2_width(geom["R"] * m * geom["ML"]) > MAX_WIDTH:
         return False
-    return mega_vmem_bytes(m, topC, refine_k, k, geom=geom) <= _vmem_budget()
+    return (mega_vmem_bytes(m, topC, refine_k, k, geom=geom)
+            <= vmem_budget(device_kind))
 
 
 # ----------------------------------------------------------- dispatch ------
 @partial(jax.jit, static_argnames=("pipe",))
 def _fused(pipe, params, members, base, queries, delta_members, tombstone):
-    """The fused fallback: the compact pipeline as ONE jitted dispatch.
-    ``pipe`` arrives already mode="compact" (the mega pipeline's twin), so
-    the jaxpr — and therefore every output bit on a deterministic backend
-    — is identical to a plain jit of the compact search."""
+    """The fused jit: the compact pipeline as ONE jitted dispatch. ``pipe``
+    arrives already mode="compact" (the mega pipeline's twin), so the
+    jaxpr — and therefore every output bit on a deterministic backend — is
+    identical to a plain jit of the compact search."""
     return pipe.search(params, members, base, queries, delta_members,
                        tombstone)
-
-
-@partial(jax.jit, static_argnames=("pipe",))
-def _fused_kernel(pipe, params, members, base, queries):
-    """The Pallas branch as ONE jitted dispatch: unpack + reshape + launch
-    all happen INSIDE this jit so the caller's trace shows exactly one
-    eqn. ``base`` is a QuantizedStore or a raw fp32 [L, d] array."""
-    from repro.store.quantized import QuantizedStore
-    if isinstance(base, QuantizedStore):
-        kind, rows, scales, exact = (base.dtype, base.codes, base.scales,
-                                     base.exact)
-        block = base.block
-    else:
-        kind, rows, scales, exact, block = "fp32", base, None, None, 1
-    return mega_query(
-        params["w1"], params["b1"], params["w2"], params["b2"], members,
-        rows, scales, exact, queries, m=pipe.m, tau=pipe.tau,
-        topC=pipe.topC, k=pipe.k, refine_k=pipe.refine_k,
-        metric=pipe.metric, kind=kind, block=block,
-        adaptive_m=pipe.adaptive_m and pipe.probe_mass < 1.0,
-        probe_mass=pipe.probe_mass, tq=DEFAULT_GEOM["tq"],
-        tb=DEFAULT_GEOM["tb"], vmem_budget=_vmem_budget())
-
-
-def _kernel_eligible(pipe, members, base, delta_members, tombstone) -> bool:
-    """Shape/state gate for the Pallas branch. Pure python over static
-    shapes — safe under an outer trace."""
-    if jax.default_backend() != "tpu":
-        return False
-    if delta_members is not None or tombstone is not None:
-        return False                      # streaming state: compact union
-    R, B, ML = members.shape
-    d = base.shape[1]
-    geom = dict(R=R, B=B, ML=ML, d=d, D=d)
-    return mega_fits(pipe.m, pipe.topC, pipe.refine_k, pipe.k, geom=geom)
 
 
 def mega_search(pipe, params, members, base, queries, delta_members=None,
                 tombstone=None):
     """mode="mega" entry (called by QueryPipeline.search): one fused
-    dispatch -> (ids [Q, k], scores [Q, k], n_candidates [Q]), bit-wise
-    the compact pipeline's output."""
-    if _kernel_eligible(pipe, members, base, delta_members, tombstone):
-        return _fused_kernel(pipe, params, members, base, queries)
+    dispatch -> (ids [Q, k], scores [Q, k], n_candidates [Q])."""
     compact = dataclasses.replace(pipe, mode="compact")
     return _fused(compact, params, members, base, queries, delta_members,
                   tombstone)

@@ -1,6 +1,6 @@
 """Dispatch site for the quantized coarse rerank (store/rerank calls HERE).
 
-TPU -> the fused Pallas kernel (quant_rerank.py). Elsewhere -> a
+``kernel=True`` -> the fused Pallas kernel (quant_rerank.py). Otherwise a
 memory-bounded jnp path that processes candidates in chunks of ``chunk``
 rows per query, so the only fp32 dequant intermediate is [Q, chunk, D] —
 the pipeline passes chunk = k' (the refine depth), making the coarse
@@ -43,19 +43,16 @@ def _coarse_chunked(queries, codes, scales, cand_ids, cand_counts, *,
 
 def quant_coarse_topk(queries, codes, scales, cand_ids, cand_counts, *,
                       tau: int, k: int, metric: str = "angular",
-                      chunk: int = 64, tq: int = 8):
+                      chunk: int = 64, kernel: bool = False, tq: int = 8):
     """Coarse top-k' over quantized code rows -> (ids [Q, k] with -1 pads,
-    coarse scores [Q, k]). Kernel on TPU, chunked jnp elsewhere — both
-    match ref.quant_rerank_ref (parity tests in tests/test_kernels.py).
+    coarse scores [Q, k]). ``kernel`` selects the Pallas kernel — the
+    choice made where the request resolved ("quant_rerank" in
+    ``SearchParams.kernels``) — else the chunked jnp path; both match
+    ref.quant_rerank_ref (parity tests in tests/test_kernels.py).
     ``scales=None`` means scale-less (bf16) codes."""
     k = min(k, cand_ids.shape[1])
-    if jax.default_backend() == "tpu":
+    if kernel:
         from repro.kernels.quant_rerank.quant_rerank import quant_rerank
-        if scales is None:
-            # the kernel's gather loop always reads a scale row; unit
-            # scales with one block spanning D keep it exact for bf16
-            # (tiny: [L, 1] fp32, ~1 MB per 2^18-row shard)
-            scales = jnp.ones((codes.shape[0], 1), jnp.float32)
         return quant_rerank(queries, codes, scales, cand_ids, cand_counts,
                             tau=tau, k=k, metric=metric, tq=tq)
     return _coarse_chunked(queries, codes, scales, cand_ids, cand_counts,

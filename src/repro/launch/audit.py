@@ -1,4 +1,6 @@
 import os
+# fake CPU devices only: pinned before jax starts, so this never takes a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=8"
                            ).strip()

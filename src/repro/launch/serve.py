@@ -50,6 +50,8 @@ def main():
                     help="max query-drift KL score (0 = rule off)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax.numpy as jnp
 
     from repro import obs

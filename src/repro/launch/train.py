@@ -32,6 +32,8 @@ def main():
     import numpy as np
 
     from repro.launch import steps as S
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.train.trainer import Trainer, TrainerConfig
 
     rng = np.random.default_rng(0)

@@ -314,7 +314,8 @@ class IRLIServer:
                 else:
                     out = [SearchResult(ids=ids[i], scores=scores[i],
                                         n_candidates=int(n_cand[i]),
-                                        epoch=res.epoch, mode=res.mode)
+                                        epoch=res.epoch, mode=res.mode,
+                                        kernels=res.kernels)
                            for i in range(n)]
             else:
                 mask, freq, _ = self.index.query(queries, m=params.m,

@@ -1,8 +1,8 @@
 """Two-stage rerank over a QuantizedStore: coarse-on-codes, exact-on-k'.
 
 Stage 1 (coarse) scores the compact candidate list [Q, C] on gathered
-QUANTIZED code rows — dispatched through kernels/quant_rerank/ops (fused
-Pallas kernel on TPU, candidate-chunked jnp elsewhere) — and keeps the k'
+QUANTIZED code rows — dispatched through kernels/quant_rerank/ops (the fused
+Pallas kernel when ``kernel`` is set, candidate-chunked jnp otherwise) — and keeps the k'
 best per query. Stage 2 (refine) gathers ONLY those k' rows at fp32 (from
 the exact tier when the store keeps one, on-the-fly dequant otherwise) and
 re-scores them with core/query.pairwise_sim — the single metric
@@ -33,7 +33,7 @@ def resolve_refine_k(refine_k: int, k: int, topC: int) -> int:
 
 def coarse_stage(queries, store: QuantizedStore, cand_ids, cand_counts, *,
                  tau: int, k: int, refine_k: int = 0,
-                 metric: str = "angular"):
+                 metric: str = "angular", kernel: bool = False):
     """Stage 1 alone: coarse top-k' survivor ids [Q, k'] (-1 pads) on
     gathered quantized code rows. Exposed separately so the pipeline's
     ``staged=True`` debug mode can fence and time it apart from the refine
@@ -46,19 +46,19 @@ def coarse_stage(queries, store: QuantizedStore, cand_ids, cand_counts, *,
     kp = resolve_refine_k(refine_k, k, cand_ids.shape[1])
     cids, _ = quant_coarse_topk(queries, store.codes, store.scales,
                                 cand_ids, cand_counts, tau=tau, k=kp,
-                                metric=metric, chunk=kp)
+                                metric=metric, chunk=kp, kernel=kernel)
     return cids
 
 
 def rerank_two_stage(queries, store: QuantizedStore, cand_ids, cand_counts,
                      *, tau: int, k: int, refine_k: int = 0,
-                     metric: str = "angular"):
+                     metric: str = "angular", kernel: bool = False):
     """queries [Q, d], cand_ids/cand_counts [Q, C] (the frequency_topC
     output) -> (ids [Q, k] with -1 where no candidate survived,
     scores [Q, k] EXACT similarities, -inf on pads). Same contract as
     core/query.rerank_gathered, which is the fp32 single-stage analogue."""
     cids = coarse_stage(queries, store, cand_ids, cand_counts, tau=tau,
-                        k=k, refine_k=refine_k, metric=metric)
+                        k=k, refine_k=refine_k, metric=metric, kernel=kernel)
     return refine_stage(queries, store, cids, k=k, metric=metric)
 
 

@@ -158,3 +158,23 @@ def test_seeded_violation_detected(seed, tmp_path):
     assert out.returncode == 1, (seed, out.returncode,
                                  out.stdout[-2000:], out.stderr[-2000:])
     assert "[FAIL] seeded." in out.stdout
+
+
+# --------------------------------------------------------- dispatch counter --
+def test_count_dispatches_sees_jit_eqns():
+    """One jitted call is one top-level dispatch; N sibling jits are N. The
+    counter reads the primitive name the installed jax gives a jit eqn, so
+    a rename shows up here instead of as a vacuous contract."""
+    from repro.analysis.jaxpr import count_dispatches
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    x = jnp.zeros((4,), jnp.float32)
+    assert count_dispatches(f, (x,)) == 1
+    for n in (2, 3):
+        fs = [jax.jit(lambda x, i=i: x + i) for i in range(n)]
+
+        def siblings(x, fs=fs):
+            return [g(x) for g in fs]
+
+        assert count_dispatches(siblings, (x,)) == n
+    # an un-jitted op sequence launches nothing of its own
+    assert count_dispatches(lambda x: x * 2.0 + 1.0, (x,)) == 0

@@ -321,3 +321,57 @@ def test_sharded_fit_matches_single_device():
     assert out["assign_match"] > 0.98, out
     assert out["re1"] == out["re4"] or all(
         abs(a - b) < 0.02 * 600 * 4 for a, b in zip(out["re1"], out["re4"]))
+
+
+_SHARDED_INDEX_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import partition as PT
+    from repro.launch.mesh import make_fit_mesh
+
+    assign = PT.hash_init(500, 16, 4, seed=3)
+    want = PT.build_inverted_index(assign, 16, 70)
+    mesh = make_fit_mesh(4, rep_axis=2)
+    explicit = jax.make_mesh((2, 2), ("data", "rep"))   # jax's default axes
+    for m in (mesh, explicit):
+        sharded = jax.device_put(assign, NamedSharding(m, P("rep", None)))
+        got = PT.build_inverted_index(sharded, 16, 70)
+        np.testing.assert_array_equal(np.asarray(got.members),
+                                      np.asarray(want.members))
+        np.testing.assert_array_equal(np.asarray(got.load),
+                                      np.asarray(want.load))
+    print("ok")
+""")
+
+
+def test_fit_reports_dropped_slots():
+    """A member width below the bucket loads loses (rep, label) slots: the
+    fit counts them in FitStats and a registry gauge, and warns."""
+    from repro import obs
+    cfg = _cfg(max_load_slack=0.5, rounds=1)
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(128, D)).astype(np.float32)
+    gt = rng.integers(0, cfg.n_labels, (128, 4)).astype(np.int32)
+    reg = obs.MetricRegistry()
+    idx = IRLIIndex(cfg)
+    with pytest.warns(RuntimeWarning, match="slots overflow"):
+        stats = idx.fit(x, gt, registry=reg)
+    held = int((np.asarray(idx.index.members) >= 0).sum())
+    assert stats.dropped_slots == idx.index.dropped > 0
+    assert held + stats.dropped_slots == cfg.n_reps * cfg.n_labels
+    assert (reg.snapshot()["fit_index_dropped_slots"]["value"]
+            == stats.dropped_slots)
+
+
+def test_build_inverted_index_accepts_rep_sharded_assign():
+    """The member-matrix rebuild after a mesh fit takes an assign sharded
+    over "rep" on a 4-device mesh — Auto axes (make_fit_mesh) or jax's
+    default Explicit ones — outside any mesh context, and equals the
+    single-device rebuild."""
+    r = subprocess.run([sys.executable, "-c", _SHARDED_INDEX_SCRIPT],
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.strip().endswith("ok")

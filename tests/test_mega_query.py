@@ -197,12 +197,20 @@ def test_kernel_interpret_parity(kind, metric, adaptive):
 
 # ----------------------------------------------- auto mode + VMEM budget ----
 def test_auto_mode_picks_mega_when_it_fits():
-    assert Q.select_mode(100_000_000, m=5, topC=1024, refine_k=0,
-                         k=10) == "mega"
-    # the typed resolve path threads its own knobs through
-    assert SearchParams().resolve(100_000_000).mode == "mega"
-    # small fp32 corpus still prefers dense (mega never beats one GEMM)
+    """Auto selection never picks mega: its Pallas kernel has not run on a
+    chip, and its fused-jit form only repackages compact. A request that
+    names mode="mega" keeps it, served by the fused jit, and no request can
+    route to the unserved "mega_query" kernel."""
+    assert Q.select_mode(100_000_000) == "compact"
+    assert SearchParams().resolve(100_000_000).mode == "compact"
+    # small fp32 corpus still prefers dense
     assert SearchParams().resolve(1_000).mode == "dense"
+    r = SearchParams(mode="mega").resolve(100_000_000)
+    assert r.mode == "mega" and r.kernels == ()
+    assert "mega_query" not in Q.KERNELS
+    for mode in ("mega", "compact"):
+        with pytest.raises(ValueError, match="drawn from"):
+            SearchParams(mode=mode, kernels=("mega_query",))
 
 
 def test_auto_mode_legacy_signature_unchanged():
@@ -213,20 +221,26 @@ def test_auto_mode_legacy_signature_unchanged():
 
 def test_auto_mode_oversized_shape_falls_back_to_compact():
     """A (m, topC) combo whose padded candidate width exceeds the sort-lane
-    cap must resolve compact instead of failing at kernel lowering."""
+    cap resolves compact, and the megakernel's fit gate refuses it — on
+    the real geometry and the device kind's own VMEM size."""
     from repro.kernels.mega_query.ops import mega_fits, mega_vmem_bytes
-    assert Q.select_mode(100_000_000, m=512, topC=32768, refine_k=0,
-                         k=10) == "compact"
-    assert not mega_fits(512, 32768, 0, 10)
+    geom = dict(d=128, H=1024, B=4096, R=2, ML=64, D=128, block=32)
     sp = SearchParams(m=512, topC=32768, k=10)
     assert sp.resolve(100_000_000).mode == "compact"
+    assert not mega_fits(512, 32768, 0, 10, geom=geom,
+                         device_kind="TPU v5 lite")
     # footprint gate (not just the width cap): widen the member lists so
     # the width stays at the cap while the VMEM residents blow the budget
-    geom = dict(ML=128)
+    # of a 16 MiB-VMEM chip
+    geom = dict(geom, ML=128)
     assert mega_vmem_bytes(128, 32768, 32768, 10, geom=geom) > \
         mega_vmem_bytes(4, 256, 64, 10, geom=geom)
-    assert not mega_fits(128, 32768, 32768, 10, geom=geom)
-    assert mega_fits(4, 256, 64, 10, geom=geom)
+    assert not mega_fits(128, 32768, 32768, 10, geom=geom,
+                         device_kind="TPU v4")
+    assert mega_fits(4, 256, 64, 10, geom=geom, device_kind="TPU v4")
+    # a device kind without a recorded VMEM size is an error, not a guess
+    with pytest.raises(ValueError, match="VMEM"):
+        mega_fits(4, 256, 64, 10, geom=geom, device_kind="TPU v99")
 
 
 def test_single_dispatch_contract_audit():

@@ -41,6 +41,26 @@ def test_inverted_index_roundtrip(L, B, seed):
 
 
 @settings(max_examples=15, deadline=None)
+@given(L=st.integers(10, 200), B=st.integers(2, 16),
+       width=st.integers(1, 8), seed=st.integers(0, 99))
+def test_inverted_index_overflow_counted(L, B, width, seed):
+    """A member width below the observed load keeps each bucket's first
+    ``width`` labels (by id) — never overwrites a kept one — and
+    ``dropped`` counts exactly the labels left out."""
+    assign = PT.hash_init(L, B, 2, seed)
+    idx = PT.build_inverted_index(assign, B, width)
+    members, a = np.asarray(idx.members), np.asarray(assign)
+    for r in range(2):
+        for b in range(B):
+            want = np.flatnonzero(a[r] == b)[:width]
+            got = members[r, b]
+            np.testing.assert_array_equal(got[:len(want)], want)
+            assert (got[len(want):] == -1).all()
+    over = np.maximum(np.asarray(idx.load) - width, 0).sum()
+    assert idx.dropped == over == 2 * L - (members >= 0).sum()
+
+
+@settings(max_examples=15, deadline=None)
 @given(L=st.integers(20, 400), B=st.integers(4, 32), K=st.integers(2, 8),
        seed=st.integers(0, 99))
 def test_kchoice_exact_load_bound(L, B, K, seed):

@@ -197,3 +197,27 @@ def test_local_search_compact_never_materializes_QL():
     report = analysis.audit("distributed.local_search_compact_no_dense_table")
     assert report.passed, report.to_dict()
     assert report.control_ok, report.control_detail
+
+
+# ------------------------------------------------ on-device exact oracle ----
+@pytest.mark.parametrize("metric", ["angular", "l2"])
+def test_exact_neighbors_matches_full_width_oracle(metric):
+    """The blocked group-max oracle returns exactly exact_topk's neighbour
+    sets (it drops only the full-width top-k), including a corpus whose
+    size is not a multiple of the group."""
+    from repro.data.synthetic import clustered_ann_device
+    base, queries, train_q = clustered_ann_device(
+        jax.random.PRNGKey(4), 1000, 64, 32, 24, n_clusters=16,
+        metric=metric)
+    assert base.shape == (1000, 24) and train_q.shape == (32, 24)
+    if metric == "angular":
+        np.testing.assert_allclose(np.linalg.norm(np.asarray(base), axis=1),
+                                   1.0, rtol=1e-5)
+    got = Q.exact_neighbors(queries, base, 10, metric=metric, q_block=16,
+                            group=128)
+    want = Q.exact_topk(queries, base, jnp.zeros(1000, bool), k=10,
+                        metric=metric)
+    np.testing.assert_array_equal(np.sort(np.asarray(got), 1),
+                                  np.sort(np.asarray(want), 1))
+    with pytest.raises(ValueError, match="multiple"):
+        Q.exact_neighbors(queries[:40], base, 10, q_block=16)

@@ -64,8 +64,8 @@ def test_params_hashable_and_resolution():
     a, b = SearchParams(m=4), SearchParams(m=4)
     assert a == b and hash(a) == hash(b) and len({a, b}) == 1
     assert SearchParams().resolve(1_000).mode == "dense"
-    # beyond the dense budget the default search shape fits the megakernel
-    assert SearchParams().resolve(100_000_000).mode == "mega"
+    # beyond the dense budget auto resolves compact (mega is never auto)
+    assert SearchParams().resolve(100_000_000).mode == "compact"
     # an oversized search shape falls back to the staged compact path
     assert SearchParams(m=512, topC=32768).resolve(
         100_000_000).mode == "compact"
@@ -73,8 +73,12 @@ def test_params_hashable_and_resolution():
     assert SearchParams(mode="compact").resolve(1_000).mode == "compact"
     with pytest.raises(ValueError, match="resolve"):
         SearchParams(mode="auto").pipeline()
-    p = SearchParams(m=3, tau=2, k=7, topC=64, mode="compact").pipeline()
+    with pytest.raises(ValueError, match="resolve"):   # kernels unresolved
+        SearchParams(mode="compact").pipeline()
+    p = SearchParams(m=3, tau=2, k=7, topC=64, mode="compact").resolve(
+        1_000).pipeline()
     assert (p.m, p.tau, p.k, p.topC, p.mode) == (3, 2, 7, 64, "compact")
+    assert p.kernels == ()                  # no TPU here: every stage jnp
 
 
 def test_searcher_protocol(frozen, mutated):
@@ -374,3 +378,62 @@ def test_default_cache_is_shared(frozen):
     idx.search(queries, base, sp)
     after = DEFAULT_CACHE.stats()
     assert after["hits"] >= before["hits"] + 1
+
+
+# ------------------------------------------------------ kernel resolution --
+def test_select_kernels_is_a_resolved_choice():
+    """Which Pallas kernels a request runs is decided once, from backend +
+    geometry, and recorded in the resolved params and the result."""
+    from repro.core import query as Q
+    from repro.core.search_api import candidate_width
+    assert Q.select_kernels("compact", "int8", 4160, "tpu") == (
+        "freq_topc", "quant_rerank")
+    # wider than the kernel's packed sort keys: the jnp sort, visibly
+    assert Q.select_kernels("compact", "int8", 40000, "tpu") == (
+        "quant_rerank",)
+    assert Q.select_kernels("compact", "fp32", None, "tpu") == ()
+    assert Q.select_kernels("mega", "fp32", 512, "tpu") == ("freq_topc",)
+    assert Q.select_kernels("dense", "fp32", 512, "tpu") == ()
+    assert Q.select_kernels("compact", "int8", 4160, "cpu") == ()
+    idx = _untrained_index()
+    assert candidate_width(5, idx.index.members) == (
+        R * 5 * idx.index.members.shape[-1])
+    base = np.random.default_rng(0).normal(size=(L, D)).astype(np.float32)
+    res = idx.search(base[:4], base, SearchParams(mode="compact"))
+    assert res.mode == "compact" and res.kernels == ()
+    with pytest.raises(ValueError, match="kernels"):
+        SearchParams(kernels=("bitonic",))
+    with pytest.raises(ValueError, match="dense"):
+        SearchParams(mode="dense", kernels=("freq_topc",))
+
+
+_CACHE_SCRIPT = """
+import os, sys
+from repro.launch.compile_cache import CACHE_DIR, enable_compile_cache
+import jax
+got = enable_compile_cache()
+print(got, jax.config.jax_compilation_cache_dir, CACHE_DIR)
+"""
+
+
+@pytest.mark.parametrize("env_dir", ["", "/nonexistent/jax-cache"])
+def test_compile_cache_dir_is_placed_from_outside(env_dir):
+    """JAX_COMPILATION_CACHE_DIR set: the helper sets nothing in code and
+    jax keeps that directory. Unset: the fixed <checkout>/.jax_cache."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    got, cfg, fixed = r.stdout.split()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fixed == os.path.join(repo, ".jax_cache")
+    if env_dir:
+        assert got == cfg == env_dir
+    else:
+        assert got == cfg == fixed

@@ -65,14 +65,13 @@ def test_search_params_store_knobs_validated():
 def test_mode_auto_accounts_code_bytes():
     """A quantized store never resolves dense — dense would decode the
     whole [L, D] corpus back to fp32 — even at corpus sizes where fp32
-    would pick dense. With the search shape known it upgrades to the
-    fused mega path (compact semantics, one dispatch); the legacy
-    knob-free entries keep resolving compact."""
+    would pick dense; auto never picks mega, whose kernel has not run
+    on a chip."""
     assert Q.select_mode(1_000) == "dense"
     assert Q.select_mode(1_000, store_dtype="int8") == "compact"
     assert SearchParams().resolve(1_000).mode == "dense"
     sp = SearchParams(store_dtype="int8")
-    assert sp.resolve(1_000).mode == "mega"
+    assert sp.resolve(1_000).mode == "compact"
     assert Q.QueryPipeline.make(1_000, store_dtype="int8").mode == "compact"
 
 
