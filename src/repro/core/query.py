@@ -454,13 +454,17 @@ class QueryPipeline:
         masking, and (``adaptive_m``) per-query probe truncation. Bucket
         selection uses raw logits — the top-m set matches scorer_probs
         under any loss while skipping a full [R, Q, B] normalize."""
-        logits = scorer_logits(params, queries)
-        vals, bidx = jax.lax.top_k(logits, self.m)
-        keep = (probe_keep_mask(logits, vals, self.probe_mass)
-                if self.adaptive_m and self.probe_mass < 1.0 else None)
-        cands = gather_members(members, bidx, delta_members, probe_keep=keep)
-        if tombstone is not None:
-            cands = mask_tombstones(cands, tombstone)
+        with jax.named_scope("scorer_logits"):
+            logits = scorer_logits(params, queries)
+        with jax.named_scope("top_m"):
+            vals, bidx = jax.lax.top_k(logits, self.m)
+            keep = (probe_keep_mask(logits, vals, self.probe_mass)
+                    if self.adaptive_m and self.probe_mass < 1.0 else None)
+        with jax.named_scope("gather"):
+            cands = gather_members(members, bidx, delta_members,
+                                   probe_keep=keep)
+            if tombstone is not None:
+                cands = mask_tombstones(cands, tombstone)
         return cands
 
     def resolve_store(self, base):
@@ -497,18 +501,25 @@ class QueryPipeline:
         cands = self.candidates(params, members, queries, delta_members,
                                 tombstone)
         if self.mode == "compact":
-            cid, cnt = frequency_topC(cands, self.topC,
-                                      kernel="freq_topc" in self.kernels)
+            with jax.named_scope("freq_topc"):
+                cid, cnt = frequency_topC(cands, self.topC,
+                                          kernel="freq_topc" in self.kernels)
             if store is not None and store.dtype != "fp32":
-                from repro.store.rerank import rerank_two_stage
-                ids, scores = rerank_two_stage(
-                    queries, store, cid, cnt, tau=self.tau, k=self.k,
-                    refine_k=self.refine_k, metric=self.metric,
-                    kernel="quant_rerank" in self.kernels)
+                from repro.store.rerank import coarse_stage, refine_stage
+                with jax.named_scope("quant_rerank"):
+                    cids = coarse_stage(
+                        queries, store, cid, cnt, tau=self.tau, k=self.k,
+                        refine_k=self.refine_k, metric=self.metric,
+                        kernel="quant_rerank" in self.kernels)
+                with jax.named_scope("refine"):
+                    ids, scores = refine_stage(queries, store, cids,
+                                               k=self.k, metric=self.metric)
             else:
                 rows = store.codes if store is not None else base
-                ids, scores = rerank_gathered(queries, rows, cid, cnt,
-                                              self.tau, self.k, self.metric)
+                with jax.named_scope("rerank"):
+                    ids, scores = rerank_gathered(queries, rows, cid, cnt,
+                                                  self.tau, self.k,
+                                                  self.metric)
             n_cand = jnp.sum((cid >= 0) & (cnt >= self.tau), axis=1)
             return ids, scores, n_cand
         if store is not None and store.dtype != "fp32":   # guarded twice:
@@ -516,12 +527,14 @@ class QueryPipeline:
                 "dense mode cannot serve a quantized store")  # config path
         rows = store.codes if store is not None else base
         L = rows.shape[0]
-        freq = candidate_frequencies_dense(cands, L)
-        mask = freq >= self.tau
-        sim = jnp.where(mask, pairwise_sim(queries, rows, self.metric),
-                        -jnp.inf)
-        scores, ids = jax.lax.top_k(sim, self.k)
-        ids = jnp.where(jnp.isfinite(scores), ids, -1)
+        with jax.named_scope("freq_dense"):
+            freq = candidate_frequencies_dense(cands, L)
+        with jax.named_scope("rerank"):
+            mask = freq >= self.tau
+            sim = jnp.where(mask, pairwise_sim(queries, rows, self.metric),
+                            -jnp.inf)
+            scores, ids = jax.lax.top_k(sim, self.k)
+            ids = jnp.where(jnp.isfinite(scores), ids, -1)
         return ids, scores, jnp.sum(mask, axis=1)
 
     def search_staged(self, params, members, base, queries,
@@ -667,9 +680,11 @@ def probe_buckets(params, queries, m: int):
     """Just the probe: top-m bucket ids [R, Q, m] per query. The serve-time
     load-observability hook (and the LIRA access-frequency prerequisite):
     IRLIServer feeds these ids into its per-bucket probe-frequency
-    VectorCounter without re-running the full pipeline."""
-    logits = scorer_logits(params, queries)
-    return jax.lax.top_k(logits, m)[1]
+    VectorCounter without re-running the full pipeline. Its ops run under
+    the ``probe_stats`` scope, apart from the search's own ``top_m``."""
+    with jax.named_scope("probe_stats"):
+        logits = scorer_logits(params, queries)
+        return jax.lax.top_k(logits, m)[1]
 
 
 def query_members(params, members: jnp.ndarray, queries, *, m: int, tau: int,

@@ -164,6 +164,7 @@ def freq_topc(cands, *, C: int, tq: int = 8, interpret: bool = False):
             jax.ShapeDtypeStruct((Qp, C_eff), jnp.float32),
         ],
         interpret=interpret,
+        name="freq_topc",   # the trace's op name: pinned, not derived
     )(padded)
     ids, cnt = ids[:Q], cnt[:Q]
     if C_eff < C:                                        # pad to requested C

@@ -130,5 +130,6 @@ def quant_rerank(queries, codes, scales, cand_ids, cand_counts, *, tau: int,
             jax.ShapeDtypeStruct((Qp, k), jnp.float32),
         ],
         interpret=interpret,
+        name="quant_rerank",   # the trace's op name: pinned, not derived
     )(queries, rows, sc_t, cand_ids, cand_counts)
     return ids[:Q], vals[:Q]

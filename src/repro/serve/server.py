@@ -36,7 +36,9 @@ resolve to ``SearchResult`` — callers that unpacked bare id rows must read
 """
 from __future__ import annotations
 
+import functools
 import inspect
+import itertools
 import queue
 import threading
 import time
@@ -137,6 +139,7 @@ class IRLIServer:
         self.auditor = auditor
         self.drift = drift
         self.q: queue.Queue = queue.Queue()
+        self._batch_ids = itertools.count()     # serve.* spans' batch_id
         self._stop = threading.Event()
         self.registry.gauge("serve_epoch").set(getattr(index, "epoch", 0))
         self.thread = threading.Thread(target=self._loop, daemon=True)
@@ -275,25 +278,47 @@ class IRLIServer:
         except Exception as e:                      # surface to the caller
             _fail(fut, e)
 
-    def _run_batch(self, batch, params: SearchParams):
+    def _run_batch(self, batch, params: SearchParams,
+                   batch_id: int | None = None):
+        """Serve one collected group. Its phases are profiler spans
+        (docs/observability.md, "Program spans"), each tagged with
+        ``batch_id``: ``serve.pad``, ``serve.probe_stats``,
+        ``serve.search`` (dispatch), ``serve.sync`` (the waits on the
+        device) and ``serve.results``. The batcher opens ``serve.batch``
+        around this call, so the batch's buffers are freed inside it."""
         n = len(batch)
         nb = self._bucket(n)
+        if batch_id is None:
+            batch_id = next(self._batch_ids)
         reg = self.registry
+        span = functools.partial(obs.trace, None, batch_id=batch_id)
         t0 = time.perf_counter()
         try:
             # stack/pad inside the try: one malformed query (wrong shape)
             # must fail ITS batch, not kill the batcher thread
-            queries = np.stack([b[0] for b in batch])
-            if nb > n:  # pad to bucket -> stable jit cache
-                queries = np.concatenate(
-                    [queries, np.repeat(queries[-1:], nb - n, 0)])
-            if self._searcher is not None:
-                if self._probe is not None:
+            with span("serve.pad"):
+                queries = np.stack([b[0] for b in batch])
+                if nb > n:  # pad to bucket -> stable jit cache
+                    queries = np.concatenate(
+                        [queries, np.repeat(queries[-1:], nb - n, 0)])
+            if self._searcher is None:
+                with span("serve.search"):
+                    mask, freq, _ = self.index.query(queries, m=params.m,
+                                                     tau=params.tau)
+                    out = list(np.asarray(mask)[:n])
+                with span("serve.results"):
+                    self._finish(batch, out, nb, t0)
+                return
+            if self._probe is not None:
+                with span("serve.probe_stats"):
                     self._record_probes(queries, n, params.m)
+            with span("serve.search"):
                 res: SearchResult = self._searcher(queries, params)
+            with span("serve.sync"):
                 ids = np.asarray(res.ids)
                 scores = np.asarray(res.scores)
                 n_cand = np.asarray(res.n_candidates)
+            with span("serve.results"):
                 reg.histogram("serve_candidates",
                               bounds=obs.COUNT_BUCKETS).observe_many(
                                   n_cand[:n])
@@ -317,16 +342,17 @@ class IRLIServer:
                                         epoch=res.epoch, mode=res.mode,
                                         kernels=res.kernels)
                            for i in range(n)]
-            else:
-                mask, freq, _ = self.index.query(queries, m=params.m,
-                                                 tau=params.tau)
-                out = list(np.asarray(mask)[:n])
+                self._finish(batch, out, nb, t0)
         except Exception as e:
             for _, fut in batch:
                 _fail(fut, e)
-            return
-        # the np.asarray conversions above already synchronized, so this
-        # duration covers dispatch + compute, not just dispatch
+
+    def _finish(self, batch, out, nb: int, t0: float) -> None:
+        """Count a served batch and fulfil its futures."""
+        n = len(batch)
+        reg = self.registry
+        # the np.asarray conversions already synchronized, so this duration
+        # covers dispatch + compute, not just dispatch
         reg.histogram("serve_batch_seconds").observe(time.perf_counter() - t0)
         reg.histogram("serve_batch_fill",
                       bounds=obs.COUNT_BUCKETS).observe(n)
@@ -349,34 +375,40 @@ class IRLIServer:
                 item, pending = pending, None
             else:
                 try:
-                    item = self.q.get(timeout=0.1)
+                    with obs.trace(None, "serve.wait"):   # an empty queue
+                        item = self.q.get(timeout=0.1)
                 except queue.Empty:
                     continue
                 if len(item) > 3:   # tolerate raw 3-tuples (tests, clients)
                     wait_hist.observe(time.perf_counter() - item[3])
             op, payload, fut = item[:3]
             if op != "query":
-                self._apply_mutation(op, payload, fut)
+                with obs.trace(None, "serve.mutation", op=op):
+                    self._apply_mutation(op, payload, fut)
                 continue
             group_params = payload[1]
             batch = [(payload[0], fut)]
-            deadline = time.time() + self.max_wait
-            while len(batch) < self.max_batch:
-                timeout = deadline - time.time()
-                if timeout <= 0:
-                    break
-                try:
-                    nxt = self.q.get(timeout=timeout)
-                except queue.Empty:
-                    break
-                if len(nxt) > 3:
-                    wait_hist.observe(time.perf_counter() - nxt[3])
-                if nxt[0] != "query" or nxt[1][1] != group_params:
-                    pending = nxt        # barrier: serve this group first
-                    break
-                batch.append((nxt[1][0], nxt[2]))
+            batch_id = next(self._batch_ids)
+            with obs.trace(None, "serve.collect", batch_id=batch_id):
+                deadline = time.time() + self.max_wait
+                while len(batch) < self.max_batch:
+                    timeout = deadline - time.time()
+                    if timeout <= 0:
+                        break
+                    try:
+                        nxt = self.q.get(timeout=timeout)
+                    except queue.Empty:
+                        break
+                    if len(nxt) > 3:
+                        wait_hist.observe(time.perf_counter() - nxt[3])
+                    if nxt[0] != "query" or nxt[1][1] != group_params:
+                        pending = nxt    # barrier: serve this group first
+                        break
+                    batch.append((nxt[1][0], nxt[2]))
             self.registry.counter("serve_param_groups_total").inc()
-            self._run_batch(batch, group_params)
+            with obs.trace(None, "serve.batch", batch_id=batch_id,
+                           fill=len(batch), bucket=self._bucket(len(batch))):
+                self._run_batch(batch, group_params, batch_id)
         # loop exited with an item parked: fail it directly — re-queueing
         # would race with close()'s drain (which may already have finished)
         if pending is not None:

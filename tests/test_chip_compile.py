@@ -66,3 +66,31 @@ def test_quant_rerank_compiles_at_deep1b_widths(one_chip, metric):
     compiled = jax.jit(lambda *a: quant_rerank(
         *a, tau=paper.SERVE_TAU, k=kp, metric=metric)).lower(*args).compile()
     assert _custom_calls(compiled) == 1
+
+
+def test_served_kernels_keep_their_instruction_names(one_chip):
+    """A trace names each device operation by its HLO instruction, and the
+    benchmark's kernel readers match ``freq_topc`` and ``quant_rerank``:
+    the served int8 program, compiled for a v5e, holds exactly those two
+    Pallas custom-calls under those names (the pallas_calls' fixed
+    ``name=``), whatever the kernel functions are called."""
+    import re
+
+    from repro.core.query import QueryPipeline
+    from repro.store.quantized import QuantizedStore
+    L, R, B, H, ML, Q = 1 << 14, 8, 512, 256, 64, 64
+    D, block = paper.D, paper.STORE_BLOCK
+    params = {"w1": _sds(one_chip, (R, D, H)), "b1": _sds(one_chip, (R, H)),
+              "w2": _sds(one_chip, (R, H, B)), "b2": _sds(one_chip, (R, B))}
+    store = QuantizedStore("int8", block, _sds(one_chip, (L, D), jnp.int8),
+                           _sds(one_chip, (L, D // block)))
+    pipe = QueryPipeline(m=paper.SERVE_M, tau=paper.SERVE_TAU,
+                         k=paper.SERVE_K, topC=TOP_C, store_dtype="int8",
+                         kernels=("freq_topc", "quant_rerank"))
+    compiled = jax.jit(pipe.search).lower(
+        params, _sds(one_chip, (R, B, ML), jnp.int32), store,
+        _sds(one_chip, (Q, D))).compile()
+    names = re.findall(r'^\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = .*'
+                       r'custom_call_target="tpu_custom_call"',
+                       compiled.as_text(), re.M)
+    assert sorted(names) == ["freq_topc", "quant_rerank"]

@@ -225,6 +225,55 @@ def test_fence_blocks_jax_arrays():
     np.testing.assert_array_equal(np.asarray(out), [0, 2, 4, 6])
 
 
+def _profiled_events(tmp_path, body) -> list:
+    """Run ``body`` under the JAX profiler -> its host events as (name,
+    {arg: value}) pairs, in the order they started."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb")))[-1]
+    events = [ev for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/host:") for line in p.lines
+              for ev in line.events]
+    return [(ev.name, {k: v for k, v in ev.stats})
+            for ev in sorted(events, key=lambda ev: ev.start_ns)]
+
+
+def test_span_without_registry_annotates_only(tmp_path):
+    """A span given no registry opens a profiler annotation under its name
+    and labels, and takes no histogram anywhere."""
+    before = obs.DEFAULT_REGISTRY.names()
+
+    def body():
+        with obs.trace(None, "unit.span", batch_id=3, fill=5) as sp:
+            time.sleep(0.001)
+        assert sp.seconds >= 0.001
+
+    events = _profiled_events(tmp_path, body)
+    assert ("unit.span", {"batch_id": 3, "fill": 5}) in events
+    assert obs.DEFAULT_REGISTRY.names() == before
+    assert "unit.span" not in before
+
+
+def test_span_annotates_and_records_on_exception(tmp_path):
+    """A span that raises still closes its annotation and observes its
+    histogram."""
+    reg = obs.MetricRegistry()
+
+    def body():
+        with pytest.raises(RuntimeError):
+            with obs.trace(reg, "op_seconds", stage="x"):
+                raise RuntimeError("boom")
+
+    events = _profiled_events(tmp_path, body)
+    assert ("op_seconds", {"stage": "x"}) in events
+    assert reg.histogram("op_seconds", {"stage": "x"}).count == 1
+
+
 # ---------------------------------------------------------- MetricsLogger --
 def test_metrics_logger_roundtrip(tmp_path):
     path = tmp_path / "m.jsonl"

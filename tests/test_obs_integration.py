@@ -5,6 +5,8 @@ the real serving path is always jitted via PipelineCache), per-stage
 histograms land in the registry, the server's legacy ``stats`` dict is a
 consistent view over its thread-safe registry, and fit/stream record their
 load-balance + churn metrics."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,15 +37,20 @@ def _fixture(L=400, n_q=8, seed=1):
     return idx, base, queries
 
 
-# ------------------------------------------------- staged == fused (jitted) --
-@pytest.mark.parametrize("mode,metric,store_dtype", [
+#: the (mode, metric, store_dtype) pipelines the staged pin and the scope
+#: check run over
+PIPELINES = [
     ("compact", "angular", "fp32"),
     ("compact", "l2", "fp32"),
     ("compact", "angular", "int8"),
     ("compact", "l2", "bf16"),
     ("dense", "angular", "fp32"),
     ("dense", "l2", "fp32"),
-])
+]
+
+
+# ------------------------------------------------- staged == fused (jitted) --
+@pytest.mark.parametrize("mode,metric,store_dtype", PIPELINES)
 def test_staged_bit_identical_to_jitted_fused(mode, metric, store_dtype):
     """search_staged (per-stage jits + inter-stage fences) must return the
     EXACT arrays of the jitted fused path — same stage functions, only the
@@ -102,6 +109,144 @@ def test_staged_records_stage_histograms():
         assert key in snap, sorted(snap)
         assert snap[key]["count"] == 1
         assert snap[key]["sum"] >= 0.0
+
+
+# ------------------------------------------------ scopes in the served program --
+#: the served program's stage scopes (core/query.py), named as staged mode
+#: names its stages
+STAGES = {"scorer_logits", "top_m", "gather", "freq_topc", "quant_rerank",
+          "refine", "rerank", "freq_dense", "probe_stats"}
+
+
+def _op_paths(lowered, fn_name: str) -> list:
+    """The name-stack path of every op of a lowered program (its last
+    component is the op itself)."""
+    text = lowered.as_text(debug_info=True)
+    return [p for p in re.findall(r'loc\("([^"]*)"', text)
+            if p.startswith(f"jit({fn_name})/")]
+
+
+def _stage(path: str) -> str | None:
+    return next((c for c in path.split("/")[:-1] if c in STAGES), None)
+
+
+@pytest.mark.parametrize("mode,metric,store_dtype", PIPELINES)
+def test_served_program_carries_stage_scopes(mode, metric, store_dtype):
+    """The program PipelineCache serves names each stage it runs, by the
+    stage names staged mode records; only the n_candidates glue between
+    stages is left out of a stage."""
+    from repro.core.search_api import PipelineCache, candidate_width
+    idx, base, queries = _fixture()
+    if store_dtype != "fp32":
+        from repro.store.quantized import encode
+        base = encode(base, dtype=store_dtype, block=8,
+                      keep_exact=(store_dtype == "int8"))
+    sp = SearchParams(mode=mode, m=M_PROBE, tau=1, k=K_TOP, topC=64,
+                      metric=metric, store_dtype=store_dtype)
+    members = idx.index.members
+    resolved = sp.resolve(base.shape[0], queries.shape[0],
+                          width=candidate_width(M_PROBE, members))
+    fn = PipelineCache().get(resolved, base.shape[0], queries.shape[0])
+    paths = _op_paths(fn.lower(idx.params, members, base, queries, None,
+                               None), "run")
+    if mode == "dense":
+        want = {"freq_dense", "rerank"}
+    elif store_dtype == "fp32":
+        want = {"freq_topc", "rerank"}
+    else:
+        want = {"freq_topc", "quant_rerank", "refine"}
+    want |= {"scorer_logits", "top_m", "gather"}
+    assert {_stage(p) for p in paths} - {None} == want
+    glue = {p.rsplit("/", 1)[1] for p in paths if _stage(p) is None}
+    assert glue <= {"reduce_sum", "ge", "and", "convert_element_type"}
+    # the probe's top-m is a scope of its own (no adaptive-m mask here)
+    assert {p.rsplit("/", 1)[1] for p in paths
+            if _stage(p) == "top_m"} == {"top_k"}
+
+
+def test_probe_buckets_runs_under_probe_stats_scope():
+    """The server's probe-statistics pass is one scope, apart from the
+    search's own top_m."""
+    idx, _, queries = _fixture()
+    paths = _op_paths(Q.probe_buckets.lower(idx.params, queries, M_PROBE),
+                      "probe_buckets")
+    assert paths and {_stage(p) for p in paths} == {"probe_stats"}
+
+
+def _host_spans(trace_dir, prefix: str) -> dict:
+    """name -> [(start_ns, end_ns, {arg: value})] of the host events whose
+    name starts with ``prefix`` in the newest trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                                / "*.xplane.pb")))[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         {k: v for k, v in ev.stats}))
+    return out
+
+
+def test_server_phases_are_profiler_spans(tmp_path):
+    """Under the profiler, every served batch is one ``serve.batch`` span
+    whose fill and bucket agree with the server's counters, its phases nest
+    inside it, its ``serve.collect`` comes before it, a mutation is a
+    ``serve.mutation`` span of its own, and the waits on an empty queue are
+    ``serve.wait`` spans."""
+    from repro.serve.server import IRLIServer
+    idx, base, queries = _fixture(seed=6)
+    sp = SearchParams(m=M_PROBE, tau=1, k=K_TOP, mode="compact")
+    server = IRLIServer(idx, params=sp, base=base, max_batch=4,
+                        max_wait_ms=1.0)
+    fill = server.registry.histogram("serve_batch_fill",
+                                     bounds=obs.COUNT_BUCKETS)
+    try:
+        server.search(np.asarray(queries[0]), timeout=120)   # compiles
+        before, fill0 = server.stats, fill.snapshot()["sum"]
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(3):
+                futs = [server.submit(np.asarray(q)) for q in queries[:6]]
+                for f in futs:
+                    f.result(timeout=120)
+            refused = server.insert(np.zeros((1, D), np.float32))
+            with pytest.raises(TypeError):      # a frozen index refuses it
+                refused.result(timeout=120)
+        after = server.stats
+    finally:
+        server.close()
+    spans = _host_spans(tmp_path, "serve.")
+    batches = {a["batch_id"]: (s, e, a) for s, e, a in spans["serve.batch"]}
+    assert len(batches) == len(spans["serve.batch"])
+    assert len(batches) == after["batches"] - before["batches"]
+    fills = [a["fill"] for _, _, a in batches.values()]
+    assert sum(fills) == after["requests"] - before["requests"] == 18
+    assert sum(fills) == fill.snapshot()["sum"] - fill0
+    assert sum(a["bucket"] - a["fill"] for _, _, a in batches.values()) \
+        == after["pad_waste"] - before["pad_waste"]
+    assert all(a["bucket"] == server._bucket(a["fill"])
+               for _, _, a in batches.values())
+    for phase in ("serve.pad", "serve.probe_stats", "serve.search",
+                  "serve.sync", "serve.results"):
+        assert sorted(a["batch_id"] for _, _, a in spans[phase]) \
+            == sorted(batches), phase
+        for s, e, a in spans[phase]:
+            bs, be, _ = batches[a["batch_id"]]
+            assert bs <= s <= e <= be, phase
+    collects = {a["batch_id"]: (s, e) for s, e, a in spans["serve.collect"]}
+    assert set(collects) == set(batches)
+    assert all(e <= batches[bid][0] for bid, (_, e) in collects.items())
+    assert [a["op"] for _, _, a in spans["serve.mutation"]] == ["insert"]
+    # between rounds the batcher waits on an empty queue, apart from batches
+    assert spans["serve.wait"]
+    assert not any(s < be and bs < e for s, e, _ in spans["serve.wait"]
+                   for bs, be, _ in batches.values())
 
 
 # ----------------------------------------------------------- server stats --
