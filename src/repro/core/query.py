@@ -44,6 +44,59 @@ def top_buckets(params, queries, m: int, loss_kind: str = "softmax_bce"):
     return jax.lax.top_k(probs, m)
 
 
+def _flip(bits):
+    """Float bits (as signed ints) <-> integers ordered as ``lax.top_k``
+    orders the floats: -NaN < -inf < ... < -0.0 < +0.0 < ... < +inf < NaN.
+    The map is its own inverse."""
+    return bits ^ ((bits >> (bits.dtype.itemsize * 8 - 1))
+                   & jnp.iinfo(bits.dtype).max)
+
+
+def _best(a, b):
+    """The better of two (key, id) pairs: the larger key, then the smaller
+    id."""
+    (ka, ia), (kb, ib) = a, b
+    take_a = (ka > kb) | ((ka == kb) & (ia < ib))
+    return jnp.where(take_a, ka, kb), jnp.where(take_a, ia, ib)
+
+
+def top_m(logits: jnp.ndarray, m: int):
+    """``jax.lax.top_k(logits, m)`` over the last (bucket) axis, exactly —
+    the same values, int32 indices and order (descending, ties to the
+    smaller index) — without the sort of every (value, index) pair that the
+    TPU lowers ``top_k`` to.
+
+    m rounds of max-and-mask: each round is one reduction to the best
+    (value, index) pair among those ranked below the previous round's pick,
+    the rest masked out of its input, so the logits are read m times and
+    never sorted. Values compare by the total order ``top_k`` uses on the
+    float bits (-0.0 below +0.0, NaN above +inf) and come back as the
+    original floats. A row of fewer than m buckets goes to ``top_k``, which
+    refuses it."""
+    if logits.shape[-1] < m:
+        return jax.lax.top_k(logits, m)
+    with jax.named_scope("max_and_mask"):
+        keys = _flip(jax.lax.bitcast_convert_type(
+            logits, jnp.dtype(f"int{logits.dtype.itemsize * 8}")))
+        ids = jax.lax.broadcasted_iota(jnp.int32, keys.shape, keys.ndim - 1)
+        low = jnp.array(jnp.iinfo(keys.dtype).min, keys.dtype)
+        none = jnp.array(jnp.iinfo(jnp.int32).max, jnp.int32)
+        k, i = keys, ids
+        out_k, out_i = [], []
+        for _ in range(m):
+            if out_i:
+                kp, ip = out_k[-1][..., None], out_i[-1][..., None]
+                below = (keys < kp) | ((keys == kp) & (ids > ip))
+                k, i = jnp.where(below, keys, low), jnp.where(below, ids, none)
+            kr, ir = jax.lax.reduce((k, i), (low, none), _best,
+                                    (keys.ndim - 1,))
+            out_k.append(kr)
+            out_i.append(ir)
+        vals = jax.lax.bitcast_convert_type(_flip(jnp.stack(out_k, -1)),
+                                            logits.dtype)
+        return vals, jnp.stack(out_i, -1)
+
+
 def gather_members(members: jnp.ndarray, bucket_idx: jnp.ndarray,
                    delta_members: jnp.ndarray | None = None,
                    probe_keep: jnp.ndarray | None = None):
@@ -457,7 +510,7 @@ class QueryPipeline:
         with jax.named_scope("scorer_logits"):
             logits = scorer_logits(params, queries)
         with jax.named_scope("top_m"):
-            vals, bidx = jax.lax.top_k(logits, self.m)
+            vals, bidx = top_m(logits, self.m)
             keep = (probe_keep_mask(logits, vals, self.probe_mass)
                     if self.adaptive_m and self.probe_mass < 1.0 else None)
         with jax.named_scope("gather"):
@@ -614,7 +667,7 @@ def _stage_logits(pipe: QueryPipeline, params, queries):
 
 @partial(jax.jit, static_argnames=("pipe",))
 def _stage_topm(pipe: QueryPipeline, logits):
-    vals, bidx = jax.lax.top_k(logits, pipe.m)
+    vals, bidx = top_m(logits, pipe.m)
     if pipe.adaptive_m and pipe.probe_mass < 1.0:
         return bidx, probe_keep_mask(logits, vals, pipe.probe_mass)
     return bidx, None
@@ -684,7 +737,7 @@ def probe_buckets(params, queries, m: int):
     the ``probe_stats`` scope, apart from the search's own ``top_m``."""
     with jax.named_scope("probe_stats"):
         logits = scorer_logits(params, queries)
-        return jax.lax.top_k(logits, m)[1]
+        return top_m(logits, m)[1]
 
 
 def query_members(params, members: jnp.ndarray, queries, *, m: int, tau: int,

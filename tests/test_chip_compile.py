@@ -94,3 +94,23 @@ def test_served_kernels_keep_their_instruction_names(one_chip):
                        r'custom_call_target="tpu_custom_call"',
                        compiled.as_text(), re.M)
     assert sorted(names) == ["freq_topc", "quant_rerank"]
+
+
+def _top_m_program(one_chip, rows, select):
+    logits = _sds(one_chip, (32, rows, paper.B_BUCKETS))
+    return jax.jit(lambda x: select(x, paper.SERVE_M)).lower(
+        logits).compile().as_text()
+
+
+@pytest.mark.parametrize("rows", [1, 512])
+def test_top_m_compiles_without_a_sort_at_deep1b_widths(one_chip, rows):
+    """The probe's top-m over B=20000 buckets, R=32 repetitions, for the
+    smallest and the largest of the server's batch buckets: the v5e program
+    sorts nothing."""
+    from repro.core.query import top_m
+    assert " sort(" not in _top_m_program(one_chip, rows, top_m)
+
+
+def test_top_k_compiles_to_a_sort_at_deep1b_widths(one_chip):
+    """The control: ``lax.top_k`` there is a sort of every bucket."""
+    assert " sort(" in _top_m_program(one_chip, 512, jax.lax.top_k)

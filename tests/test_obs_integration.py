@@ -159,9 +159,11 @@ def test_served_program_carries_stage_scopes(mode, metric, store_dtype):
     assert {_stage(p) for p in paths} - {None} == want
     glue = {p.rsplit("/", 1)[1] for p in paths if _stage(p) is None}
     assert glue <= {"reduce_sum", "ge", "and", "convert_element_type"}
-    # the probe's top-m is a scope of its own (no adaptive-m mask here)
-    assert {p.rsplit("/", 1)[1] for p in paths
-            if _stage(p) == "top_m"} == {"top_k"}
+    # the probe's top-m is a scope of its own (no adaptive-m mask here):
+    # query.top_m's rounds, all in their inner scope, and no top_k
+    top = [p for p in paths if _stage(p) == "top_m"]
+    assert top and all("/top_m/max_and_mask/" in p for p in top), top
+    assert "top_k" not in {p.rsplit("/", 1)[1] for p in top}
 
 
 def test_probe_buckets_runs_under_probe_stats_scope():
