@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import search_api as SA
 from repro.core.search_api import SearchParams, SearchResult
@@ -136,13 +136,16 @@ def local_search(scorer_params, members, base_shard, queries,
 
 def _merge_across_shards(ids, scores, n_cand, k: int, axes):
     """all_gather the tiny [Q, k] per-shard winners (ids already globalized
-    by the caller), take the global top-k, psum the survivor counts."""
-    all_scores = jax.lax.all_gather(scores, axes, axis=1)     # [Q, P, k]
-    all_ids = jax.lax.all_gather(ids, axes, axis=1)
-    Qn = scores.shape[0]
-    best, pos = jax.lax.top_k(all_scores.reshape(Qn, -1), k)
-    merged = jnp.take_along_axis(all_ids.reshape(Qn, -1), pos, axis=1)
-    return merged, best, jax.lax.psum(n_cand, axes)
+    by the caller), take the global top-k, psum the survivor counts. Runs
+    under the ``merge`` device scope, so a trace can tell the cross-shard
+    work (and a fast shard's wait for the slowest) from the shard's own."""
+    with jax.named_scope("merge"):
+        all_scores = jax.lax.all_gather(scores, axes, axis=1)  # [Q, P, k]
+        all_ids = jax.lax.all_gather(ids, axes, axis=1)
+        Qn = scores.shape[0]
+        best, pos = jax.lax.top_k(all_scores.reshape(Qn, -1), k)
+        merged = jnp.take_along_axis(all_ids.reshape(Qn, -1), pos, axis=1)
+        return merged, best, jax.lax.psum(n_cand, axes)
 
 
 def make_distributed_search(mesh: Mesh, params: SearchParams | None = None, *,
@@ -287,8 +290,15 @@ def make_production_search(mesh: Mesh, params: SearchParams | None = None, *,
 
       scorer_params: replicated stacked R-rep scorer
       members: [P, R, B, ML] per-shard inverted indexes (P = mesh size)
-      base:    [P, L_loc, d] per-shard corpora
+      base:    [P, L_loc, d] per-shard corpora (or a QuantizedStore whose
+               every leaf leads with P)
       queries: [Q, d] replicated
+
+    :func:`place_shards` lays such inputs out from pieces built on each
+    chip. The shard_map'd search is built and jitted here, once: repeated
+    calls at one shape trace and compile nothing, and nothing is indexed
+    eagerly on a global array, so meshes with Auto and Explicit axes serve
+    alike.
 
     Typed path: ``make_production_search(mesh, SearchParams(...))`` returns
     ``search(...) -> SearchResult`` with GLOBAL ids merged across shards and
@@ -320,22 +330,54 @@ def make_production_search(mesh: Mesh, params: SearchParams | None = None, *,
         gids = jnp.where(ids >= 0, ids + shard * base.shape[0], -1)
         return _merge_across_shards(gids, scores, n_cand, sp.k, axes)
 
+    # a spec shorter than an array's rank leaves the trailing dims
+    # replicated, and one spec for the base covers every leaf of a
+    # QuantizedStore, so the specs do not depend on the base's structure
+    mapped = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(axes), P(axes), P()),
+        out_specs=(P(), P(), P()), check_vma=False))
+
     def search(scorer_params, members, base, queries):
-        mapped = jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(P(), P(axes, None, None, None),
-                      _base_specs(base, axes), P()),
-            out_specs=(P(), P(), P()),
-            check_vma=False)
         ids, scores, n_cand = mapped(scorer_params, members, base, queries)
         if legacy:
             return ids, scores
-        r = _resolve(sp, base.shape[1], queries.shape[0], members[0],
+        r = _resolve(sp, base.shape[1], queries.shape[0],
+                     jax.ShapeDtypeStruct(members.shape[1:], members.dtype),
                      force_compact=True)
         return SearchResult(ids=ids, scores=scores, n_candidates=n_cand,
                             epoch=epoch, mode="compact", kernels=r.kernels)
 
     return search
+
+
+def place_shards(mesh: Mesh, scorer_params, members, bases):
+    """Lay per-shard pieces onto ``mesh`` as :func:`make_production_search`
+    takes them -> (scorer_params, members [P, R, B, ML], base [P, L_loc,
+    d]).
+
+    ``members[s]`` and ``bases[s]`` (an array or a QuantizedStore) are shard
+    s's index and corpus; each piece goes to the device that holds shard s
+    of the global array, and a piece built on that device is not moved, so
+    a shard's index never passes through another chip. The scorer is
+    replicated on every device."""
+    axes = tuple(mesh.axis_names)
+    n = mesh.devices.size
+    if len(members) != n or len(bases) != n:
+        raise ValueError(f"the mesh has {n} devices but got "
+                         f"{len(members)} member matrices and {len(bases)} "
+                         "bases")
+    spec = NamedSharding(mesh, P(axes))
+
+    def stack(*pieces):
+        shape = (n,) + pieces[0].shape
+        where = spec.addressable_devices_indices_map(shape)
+        return jax.make_array_from_single_device_arrays(
+            shape, spec, [jax.device_put(pieces[idx[0].indices(n)[0]][None],
+                                         dev)
+                          for dev, idx in where.items()])
+
+    return (jax.device_put(scorer_params, NamedSharding(mesh, P())),
+            stack(*members), jax.tree.map(stack, *bases))
 
 
 # ------------------------------------------------------- static contracts --

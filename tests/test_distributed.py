@@ -7,6 +7,7 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 _SCRIPT = textwrap.dedent("""
@@ -125,3 +126,199 @@ def test_production_search_matches_reference():
     assert rec["ok_dist_scores"], rec
     assert rec["ok_dist_ids"], rec
     assert rec["ok_dist_ncand"], rec
+
+
+# ---------------------------------------------------------------------------
+# The production search as one compiled program, on four fake devices: one
+# subprocess (the device count is fixed when jax starts) whose readings the
+# tests below assert on.
+_FOUR = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, jax.numpy as jnp
+    import jax.monitoring as mon
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.analysis import audit, load_all
+    from repro.core.distributed import (make_production_search, place_shards,
+                                        shard_search_local)
+    from repro.core.network import ScorerConfig, scorer_init
+    from repro.core.partition import build_inverted_index, hash_init
+    from repro.core.search_api import SearchParams
+    from repro.launch.mesh import make_host_mesh
+    from repro.store import encode
+
+    counts = {"traces": 0, "compiles": 0}
+
+    def on_duration(name, _secs, **_):
+        if name == "/jax/core/compile/jaxpr_trace_duration":
+            counts["traces"] += 1
+        elif name == "/jax/core/compile/backend_compile_duration":
+            counts["compiles"] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)
+
+    P_N, L, D, B, R, Q, K = 4, 256, 32, 16, 4, 16, 5
+    rng = np.random.default_rng(0)
+    scorer = scorer_init(jax.random.PRNGKey(0), ScorerConfig(
+        d_in=D, d_hidden=32, n_buckets=B, n_reps=R))
+    bases = [rng.normal(size=(L, D)).astype(np.float32) for _ in range(P_N)]
+    queries = rng.normal(size=(Q, D)).astype(np.float32)
+    # a cross-shard tie: shard 2 holds a copy of shard 0's row 7, and query
+    # 0 is that row, so both copies score the same
+    bases[2][40] = bases[0][7]
+    queries[0] = bases[0][7]
+    stores = [encode(b, "int8", 16) for b in bases]
+    members = [build_inverted_index(hash_init(L, B, R, seed=s), B,
+                                    max_load=2 * L // B).members
+               for s in range(P_N)]
+    # every bucket probed and every candidate kept: the exact top-k
+    sp = SearchParams(m=B, tau=1, k=K, topC=L, store_dtype="int8")
+    out = {}
+
+    def run(mesh):
+        sc, mem, st = place_shards(mesh, scorer, members, stores)
+        q = jax.device_put(queries, NamedSharding(mesh, PartitionSpec()))
+        search = make_production_search(mesh, sp)
+        res = search(sc, mem, st, q)
+        jax.block_until_ready(res.ids)
+        before = dict(counts)
+        for _ in range(5):
+            again = search(sc, mem, st, q)
+            jax.block_until_ready(again.ids)
+        after = {k: counts[k] - before[k] for k in counts}
+        return res, after, (sc, mem, st, q), search
+
+    auto = make_host_mesh(P_N)
+    res, after, args, search = run(auto)
+    out["repeat"] = after
+    sc, mem, st, q = args
+    flat = list(auto.devices.flat)
+    out["placed"] = {
+        name: sorted([sh.index[0].start, flat.index(sh.device)]
+                     for sh in arr.addressable_shards)
+        for name, arr in (("members", mem), ("codes", st.codes),
+                          ("scales", st.scales))}
+
+    explicit = jax.make_mesh((P_N,), ("data",))
+    eres, eafter, _, _ = run(explicit)
+    out["explicit_axes"] = [str(t) for t in explicit.axis_types]
+    out["explicit_equal"] = bool(
+        np.array_equal(np.asarray(eres.ids), np.asarray(res.ids))
+        and np.array_equal(np.asarray(eres.scores), np.asarray(res.scores)))
+    out["explicit_repeat"] = eafter
+
+    # the host merge of four single-device shard searches: shard order,
+    # then rank within a shard, so a tie goes to the lower global id
+    ids, scores, ncand = [], [], 0
+    for s in range(P_N):
+        r = shard_search_local(scorer, members[s], stores[s],
+                               jnp.asarray(queries), sp)
+        i = np.asarray(r.ids)
+        ids.append(np.where(i >= 0, i + s * L, -1))
+        scores.append(np.asarray(r.scores))
+        ncand = ncand + np.asarray(r.n_candidates)
+    ids, scores = np.concatenate(ids, 1), np.concatenate(scores, 1)
+    order = np.argsort(-scores, axis=1, kind="stable")[:, :K]
+    out["host_ids"] = np.take_along_axis(ids, order, 1).tolist()
+    out["host_scores"] = np.take_along_axis(scores, order, 1).tolist()
+    out["host_ncand"] = ncand.tolist()
+    out["ids"] = np.asarray(res.ids).tolist()
+    out["scores"] = np.asarray(res.scores).tolist()
+    out["ncand"] = np.asarray(res.n_candidates).tolist()
+    out["tie"] = [7, 2 * L + 40]
+
+    def arrays(*a):
+        r = search(*a)
+        return r.ids, r.scores, r.n_candidates
+
+    hlo = jax.jit(arrays).lower(sc, mem, st, q).compile().as_text()
+    out["merge_collectives"] = sorted(
+        line.split("=", 2)[1].split("(")[0].strip().split(" ")[-1]
+        for line in hlo.splitlines()
+        if ("all-gather" in line or "all-reduce" in line)
+        and "/merge/" in line)
+    load_all()
+    out["contract"] = audit("distributed.production_merge_collectives").passed
+    print(json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def four():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", _FOUR], env=env,
+                         capture_output=True, text=True, timeout=420)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_production_search_compiles_once(four):
+    """After the first call, repeated calls at one shape trace and compile
+    nothing."""
+    assert four["repeat"] == {"traces": 0, "compiles": 0}
+    assert four["explicit_repeat"] == {"traces": 0, "compiles": 0}
+
+
+def test_production_search_on_auto_and_explicit_meshes(four):
+    assert four["explicit_axes"] == ["AxisType.Explicit"]
+    assert four["explicit_equal"]
+
+
+def test_place_shards_keeps_each_shard_on_its_device(four):
+    for name in ("members", "codes", "scales"):
+        assert four["placed"][name] == [[s, s] for s in range(4)], name
+
+
+def test_production_search_equals_host_merge(four):
+    assert four["ids"] == four["host_ids"]
+    np.testing.assert_allclose(four["scores"], four["host_scores"],
+                               rtol=1e-6)
+    assert four["ncand"] == four["host_ncand"]
+    # the tied copies both make the top-k, the lower global id first
+    assert four["ids"][0][:2] == four["tie"]
+    assert four["scores"][0][0] == four["scores"][0][1]
+
+
+def test_merge_scope_in_the_compiled_program(four):
+    """The all-gathers and the all-reduce of the merge carry its scope, and
+    the registered collective contract holds on four devices."""
+    # the scores' and the ids' all-gathers, and the counts' psum
+    assert four["merge_collectives"] == ["all-gather", "all-gather",
+                                         "all-reduce"]
+    assert four["contract"]
+
+
+def test_place_shards_on_one_device():
+    """A one-device mesh takes one shard whole: ``place_shards`` and the
+    production search serve it as the single-shard search does."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.distributed import (make_production_search, place_shards,
+                                        shard_search_local)
+    from repro.core.network import ScorerConfig, scorer_init
+    from repro.core.partition import build_inverted_index, hash_init
+    from repro.core.search_api import SearchParams
+    from repro.launch.mesh import make_host_mesh
+    from repro.store import encode
+
+    L, D, B, R = 256, 16, 16, 4
+    rng = np.random.default_rng(3)
+    scorer = scorer_init(jax.random.PRNGKey(3), ScorerConfig(
+        d_in=D, d_hidden=32, n_buckets=B, n_reps=R))
+    store = encode(rng.normal(size=(L, D)).astype(np.float32), "int8", 16)
+    members = build_inverted_index(hash_init(L, B, R, seed=3), B,
+                                   max_load=2 * L // B).members
+    queries = jnp.asarray(rng.normal(size=(8, D)), jnp.float32)
+    sp = SearchParams(m=4, tau=1, k=5, store_dtype="int8")
+    mesh = make_host_mesh(1)
+    sc, mem, st = place_shards(mesh, scorer, [members], [store])
+    assert mem.shape == (1,) + members.shape
+    assert st.codes.shape == (1,) + store.codes.shape
+    got = make_production_search(mesh, sp)(sc, mem, st, queries)
+    want = shard_search_local(scorer, members, store, queries, sp)
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want.ids))
+    np.testing.assert_allclose(np.asarray(got.scores),
+                               np.asarray(want.scores), rtol=1e-6)
