@@ -223,17 +223,24 @@ def sorted_frequency_topC(cands: jnp.ndarray, C: int):
     return jax.vmap(one)(cands)
 
 
-def frequency_topC(cands: jnp.ndarray, C: int, kernel: bool = False):
+def frequency_topC(cands: jnp.ndarray, C: int, kernel: bool = False, *,
+                   id_bound: int | None = None,
+                   max_count: int | None = None):
     """FrequentOnes over gathered candidates -> compact (ids, counts) [Q, C].
 
     Dispatches through kernels/freq_topc/ops (the ONE dispatch site): the
-    fused Pallas kernel — per-query bitonic sort + run-length count + top-C,
-    VMEM-resident — when ``kernel`` is set (the resolved choice, see
-    :func:`select_kernels`), the jnp sorted path otherwise. Both produce
-    identical output (count desc, id asc on ties; -1/0 padding past the
-    distinct-candidate count)."""
+    fused Pallas kernel — per-query bitonic sort + run-length count, then a
+    truncated bitonic top-C network, VMEM-resident — when ``kernel`` is set
+    (the resolved choice, see :func:`select_kernels`), the jnp sorted path
+    otherwise. The network sorts ``(count, id)`` keys where the static
+    bounds ``id_bound`` (every id is below it) and ``max_count`` (no id
+    occurs more often in a row) make them fit int32, else ``(count,
+    lane)`` keys carrying the id (``freq_topc.key_form``); the jnp path
+    ignores the bounds. All produce identical output (count desc, id asc on
+    ties; -1/0 padding past the distinct-candidate count)."""
     from repro.kernels.freq_topc.ops import frequent_topc
-    return frequent_topc(cands, C=C, kernel=kernel)
+    return frequent_topc(cands, C=C, kernel=kernel, id_bound=id_bound,
+                         max_count=max_count)
 
 
 def pairwise_sim(queries, base, metric: str = "angular"):
@@ -520,6 +527,16 @@ class QueryPipeline:
                 cands = mask_tombstones(cands, tombstone)
         return cands
 
+    def freq_bounds(self, members, base, delta_members=None) -> dict:
+        """Static bounds on the frequency stage's candidate rows, from
+        shapes alone: every id is a row of ``base`` (``id_bound``), and
+        ``gather_members`` puts an id at most once in each of the R·m
+        member lists it gathers, and in each delta segment (``max_count``)
+        — with tombstones and ``adaptive_m`` too, which only mask."""
+        segments = 1 if delta_members is None else 2
+        return {"id_bound": int(base.shape[0]),
+                "max_count": members.shape[0] * self.m * segments}
+
     def resolve_store(self, base):
         """Validate ``base`` against ``store_dtype`` and return the
         QuantizedStore if one was passed (else None). Shared by the fused
@@ -555,8 +572,9 @@ class QueryPipeline:
                                 tombstone)
         if self.mode == "compact":
             with jax.named_scope("freq_topc"):
-                cid, cnt = frequency_topC(cands, self.topC,
-                                          kernel="freq_topc" in self.kernels)
+                cid, cnt = frequency_topC(
+                    cands, self.topC, kernel="freq_topc" in self.kernels,
+                    **self.freq_bounds(members, base, delta_members))
             if store is not None and store.dtype != "fp32":
                 from repro.store.rerank import coarse_stage, refine_stage
                 with jax.named_scope("quant_rerank"):
@@ -606,9 +624,9 @@ class QueryPipeline:
         reg = obs.get_registry(registry)
         store = self.resolve_store(base)
 
-        def run(stage, fn, *args):
+        def run(stage, fn, *args, **static):
             with obs.trace(reg, "serve_stage_seconds", stage=stage) as sp:
-                return sp.fence(fn(self, *args))
+                return sp.fence(fn(self, *args, **static))
 
         if self.mode == "mega":
             # the whole fused search IS the stage: one dispatch, one timing
@@ -623,7 +641,9 @@ class QueryPipeline:
         cands = run("gather", _stage_gather, members, bidx, keep,
                     delta_members, tombstone)
         if self.mode == "compact":
-            cid, cnt, n_cand = run("freq_topc", _stage_freq_topc, cands)
+            cid, cnt, n_cand = run(
+                "freq_topc", _stage_freq_topc, cands,
+                **self.freq_bounds(members, base, delta_members))
             if store is not None and store.dtype != "fp32":
                 cids = run("quant_rerank", _stage_quant_coarse, queries,
                            store, cid, cnt)
@@ -684,10 +704,12 @@ def _stage_gather(pipe: QueryPipeline, members, bidx, probe_keep,
     return cands
 
 
-@partial(jax.jit, static_argnames=("pipe",))
-def _stage_freq_topc(pipe: QueryPipeline, cands):
+@partial(jax.jit, static_argnames=("pipe", "id_bound", "max_count"))
+def _stage_freq_topc(pipe: QueryPipeline, cands, *, id_bound=None,
+                     max_count=None):
     cid, cnt = frequency_topC(cands, pipe.topC,
-                              kernel="freq_topc" in pipe.kernels)
+                              kernel="freq_topc" in pipe.kernels,
+                              id_bound=id_bound, max_count=max_count)
     n_cand = jnp.sum((cid >= 0) & (cnt >= pipe.tau), axis=1)
     return cid, cnt, n_cand
 

@@ -218,8 +218,11 @@ class PipelineCache:
     ``cache_{hits,misses,compiles}_total`` counters of ``registry`` (the
     process-wide ``obs.DEFAULT_REGISTRY`` when None), and :meth:`search`
     times the FIRST invocation of every fresh entry — trace + XLA compile +
-    first run, fenced — into the ``cache_compile_seconds`` histogram. The
-    legacy ``stats()`` dict keeps its exact four-key shape.
+    first run, fenced — into the ``cache_compile_seconds`` histogram. A
+    compile whose pipeline runs the frequency kernel also counts into
+    ``freq_topc_compiles_total{keys="id"|"lane"}``, by the key form the
+    kernel's top-C network sorts at those shapes. The legacy ``stats()``
+    dict keeps its exact four-key shape.
     """
 
     def __init__(self, registry=None):
@@ -271,6 +274,12 @@ class PipelineCache:
                     tombstone):
                 self.compiles += 1      # trace-time only: counts compilations
                 self.registry.counter("cache_compiles_total").inc()
+                if "freq_topc" in pipe.kernels:   # the keys its top-C sorts
+                    from repro.kernels.freq_topc.freq_topc import key_form
+                    keys = key_form(**pipe.freq_bounds(members, base,
+                                                       delta_members))
+                    self.registry.counter("freq_topc_compiles_total",
+                                          {"keys": keys}).inc()
                 return pipe.search(scorer_params, members, base, queries,
                                    delta_members, tombstone)
 

@@ -9,9 +9,11 @@ from repro.kernels.freq_topc.freq_topc import freq_topc
 from repro.kernels.freq_topc.ref import freq_topc_ref
 
 
-def frequent_topc(cands, *, C: int, kernel: bool = False, tq: int = 8):
+def frequent_topc(cands, *, C: int, kernel: bool = False, tq: int = 8,
+                  id_bound: int | None = None, max_count: int | None = None):
     if kernel:
-        return freq_topc(cands, C=C, tq=tq)
+        return freq_topc(cands, C=C, tq=tq, id_bound=id_bound,
+                         max_count=max_count)
     return freq_topc_ref(cands, C=C)
 
 

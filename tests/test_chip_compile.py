@@ -42,12 +42,18 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-def test_freq_topc_compiles_at_smoke_width(one_chip):
+@pytest.mark.parametrize("form", ["id", "lane"])
+def test_freq_topc_compiles_at_smoke_width(one_chip, form):
+    """Both key forms of the top-C network: (count, id) under the smoke
+    shard's bounds, (count, lane) without bounds."""
     from repro.kernels.freq_topc.freq_topc import (MAX_WIDTH, freq_topc,
-                                                   pow2_width)
+                                                   key_form, pow2_width)
     assert pow2_width(WIDTH) <= MAX_WIDTH     # resolution selects the kernel
+    bounds = ({"id_bound": L_SMOKE, "max_count": R_SMOKE * paper.SERVE_M}
+              if form == "id" else {})
+    assert key_form(bounds.get("id_bound"), bounds.get("max_count")) == form
     cands = _sds(one_chip, (Q_BATCH, WIDTH), jnp.int32)
-    compiled = jax.jit(lambda c: freq_topc(c, C=TOP_C)).lower(
+    compiled = jax.jit(lambda c: freq_topc(c, C=TOP_C, **bounds)).lower(
         cands).compile()
     assert _custom_calls(compiled) == 1
 

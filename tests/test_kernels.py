@@ -93,23 +93,129 @@ from repro.kernels.freq_topc.freq_topc import freq_topc
 from repro.kernels.freq_topc.ref import freq_topc_ref
 
 
-@pytest.mark.parametrize("Q,C0,V,C,tq", [
-    (8, 96, 40, 16, 4),      # fewer values than slots: heavy duplication
-    (7, 120, 500, 64, 4),    # mostly-distinct + row padding (7 % 4 != 0)
-    (4, 100, 30, 160, 2),    # C > C0: output right-padded
-])
-def test_freq_topc_matches_ref_exactly(Q, C0, V, C, tq):
-    rng = np.random.default_rng(Q + C0)
-    cands = rng.integers(-1, V, (Q, C0)).astype(np.int32)
-    cands[0, : C0 // 2] = -1                     # heavily padded row
-    cands[-1] = -1                               # zero-candidate row
+def _freq_case(case):
+    """-> (cands [Q, C0] int32, C, tq, id_bound, max_count) for one case of
+    the kernel's exact-equality test; the bounds hold for the rows."""
+    rng = np.random.default_rng(FREQ_CASES.index(case))
+    if case in ("dup", "distinct", "c_over_c0"):   # the original three
+        Q, C0, V, C, tq = {"dup": (8, 96, 40, 16, 4),          # heavy dups
+                           "distinct": (7, 120, 500, 64, 4),   # 7 % 4 != 0
+                           "c_over_c0": (4, 100, 30, 160, 2)}[case]
+        rng = np.random.default_rng(Q + C0)
+        cands = rng.integers(-1, V, (Q, C0)).astype(np.int32)
+        cands[0, : C0 // 2] = -1                     # heavily padded row
+        cands[-1] = -1                               # zero-candidate row
+        return cands, C, tq, V, C0
+    if case == "ties_at_cut":
+        # 300 distinct ids per row, counts 1-3 so the 100th place falls
+        # inside a long run of equal counts; width 1024 > C' = 128, so the
+        # halving rounds run three times
+        Q, C, V = 8, 100, 5000
+        rows = []
+        for _ in range(Q):
+            ids = rng.choice(V, 300, replace=False)
+            reps = rng.integers(1, 4, 300)
+            row = np.repeat(ids, reps)
+            rows.append(np.pad(rng.permutation(row), (0, 1000 - len(row)),
+                               constant_values=-1))
+        return np.stack(rows).astype(np.int32), C, 8, V, 3
+    if case == "bound_edges":
+        # an id at max_count, ids 0 and id_bound - 1, with (max_count + 1)
+        # * 2^bits(id_bound - 1) exactly 2^31: the largest key is INT32_MAX
+        id_bound, max_count, C0 = 1 << 21, 1023, 1100
+        cands = rng.integers(0, id_bound, (8, C0)).astype(np.int32)
+        cands[0, :max_count] = 0
+        cands[1, :max_count] = id_bound - 1
+        cands[2, :7] = id_bound - 1
+        cands[2, 7:14] = 0
+        cands[3] = -1                                # all-pad row
+        cands[4, 5:] = -1                            # fewer ids than C
+        return cands, 256, 8, id_bound, max_count
+    if case == "past_int32":
+        # (1024 + 1) << 21 > 2^31: given bounds, the network still takes the
+        # lane form; ids near the bound, 600 slots > C' = 128
+        id_bound, max_count = 1 << 21, 1024
+        cands = rng.integers(id_bound - 200, id_bound, (4, 600))
+        return cands.astype(np.int32), 64, 4, id_bound, max_count
+    raise ValueError(case)
+
+
+FREQ_CASES = ["dup", "distinct", "c_over_c0", "ties_at_cut", "bound_edges",
+              "past_int32"]
+
+
+@pytest.mark.parametrize("bounds", ["given", "none"])
+@pytest.mark.parametrize("case", FREQ_CASES)
+def test_freq_topc_matches_ref_exactly(case, bounds):
+    """Bit for bit the jnp oracle's ids and counts (count desc, id asc on
+    ties, -1/0 pads), in either key form of the top-C network: the id form
+    where the bounds are given and fit int32, the lane form otherwise."""
+    from repro.kernels.freq_topc.freq_topc import key_form
+    cands, C, tq, id_bound, max_count = _freq_case(case)
+    kw = {"id_bound": id_bound, "max_count": max_count} \
+        if bounds == "given" else {}
+    form = "id" if kw and case != "past_int32" else "lane"
+    assert key_form(kw.get("id_bound"), kw.get("max_count")) == form
     cj = jnp.asarray(cands)
-    ids_k, cnt_k = freq_topc(cj, C=C, tq=tq, interpret=True)
+    ids_k, cnt_k = freq_topc(cj, C=C, tq=tq, interpret=True, **kw)
     ids_r, cnt_r = freq_topc_ref(cj, C=C)
-    # deterministic ordering contract (count desc, id asc) -> exact equality
     np.testing.assert_array_equal(np.asarray(ids_k), np.asarray(ids_r))
     np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_r))
-    assert (np.asarray(ids_k)[-1] == -1).all()   # empty row stays empty
+    empty = (cands == -1).all(axis=1)
+    assert (np.asarray(ids_k)[empty] == -1).all()   # empty rows stay empty
+
+
+def test_freq_topc_key_form_at_deep1b_widths():
+    """A Deep1B shard (L = 2,040,320 rows, R = 32, m = 5, member width 204)
+    takes the (count, id) form: 160 member lists a row and 21-bit ids; a
+    2^26-row shard's 26-bit ids do not fit beside the counts."""
+    from repro.core.query import QueryPipeline
+    from repro.kernels.freq_topc.freq_topc import key_form
+    R, m, ML, L = 32, 5, 204, 2_040_320
+    assert key_form(L, R * m) == "id"
+    assert key_form(1 << 26, R * m) == "lane"
+    assert key_form(1 << 21, 1023) == "id"       # (1023 + 1) << 21 == 2^31
+    assert key_form(1 << 21, 1024) == "lane"
+    pipe = QueryPipeline(m=m, kernels=("freq_topc",))
+    members = jax.ShapeDtypeStruct((R, 20000, ML), jnp.int32)
+    rows = lambda n: jax.ShapeDtypeStruct((n, 96), jnp.int8)
+    bounds = pipe.freq_bounds(members, rows(L))
+    assert bounds == {"id_bound": L, "max_count": R * m}
+    assert key_form(**bounds) == "id"
+    assert key_form(**pipe.freq_bounds(members, rows(1 << 26))) == "lane"
+    # delta segments double the lists a row gathers; still the id form
+    delta = jax.ShapeDtypeStruct((R, 20000, 16), jnp.int32)
+    bounds = pipe.freq_bounds(members, rows(L), delta)
+    assert bounds["max_count"] == 2 * R * m and key_form(**bounds) == "id"
+
+
+def test_freq_topc_compiles_counted_by_key_form():
+    """PipelineCache counts each compile of a pipeline that runs the kernel
+    into ``freq_topc_compiles_total{keys=...}`` of its registry: one per
+    form here (traced only, at abstract shapes), none for a cache hit."""
+    from repro import obs
+    from repro.core.search_api import PipelineCache, SearchParams
+    reg = obs.MetricRegistry()
+    cache = PipelineCache(registry=reg)
+    params = SearchParams(mode="compact", kernels=("freq_topc",), topC=256)
+    R, B, ML, d, Qn = 4, 64, 32, 16, 8
+    sds = jax.ShapeDtypeStruct
+    scorer = {"w1": sds((R, d, 32), jnp.float32),
+              "b1": sds((R, 32), jnp.float32),
+              "w2": sds((R, 32, B), jnp.float32),
+              "b2": sds((R, B), jnp.float32)}
+    members = sds((R, B, ML), jnp.int32)
+    queries = sds((Qn, d), jnp.float32)
+
+    def compiles(form):
+        return reg.counter("freq_topc_compiles_total", {"keys": form}).value
+
+    for L in (4096, 1 << 29, 4096):
+        base = sds((L, d), jnp.float32)
+        jax.eval_shape(cache.get(params, L, Qn), scorer, members, base,
+                       queries, None, None)
+    assert (compiles("id"), compiles("lane")) == (1, 1)
+    assert cache.compiles == 2
 
 
 def test_freq_topc_ref_matches_core_sorted_path():
